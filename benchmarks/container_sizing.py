@@ -42,6 +42,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     ExhaustiveSource,
     SizingController,
@@ -377,6 +378,7 @@ if __name__ == "__main__":
                     help="reduced budgets, skip the large-DAG case "
                          "(tier-1 CI)")
     args = ap.parse_args()
+    enable_compile_cache()
     res = container_sizing(smoke=args.smoke)
     print(json.dumps({k: v for k, v in res.items() if k != "checks"},
                      indent=2))

@@ -23,6 +23,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     EC2_CATALOG_ADJUSTED,
     FleetController,
@@ -216,4 +217,5 @@ def run_all() -> list[dict]:
 
 if __name__ == "__main__":
     import json
+    enable_compile_cache()
     print(json.dumps(run_all(), indent=2))

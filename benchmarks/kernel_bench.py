@@ -1,9 +1,11 @@
 """Kernel benchmark: correctness sweep + static VMEM/roofline accounting.
 
-This container has no TPU, so wall-clock kernel timing is meaningless;
-what CAN be verified without hardware is (a) numerical equivalence at
-production tile shapes and (b) the static working-set / arithmetic-
-intensity accounting that justifies the BlockSpec choices (DESIGN.md).
+Run on the host it checks (a) numerical equivalence at production tile
+shapes, with the kernels in interpret mode, and (b) the static working-set
+/ arithmetic-intensity accounting that justifies the BlockSpec choices;
+its wall clock is the interpreter's, not a kernel time.  The main-path
+kernels are compiled for the TPU v5e target by ``tests/test_tpu_compile.py``
+and run on the chip against their references by ``chip_smoke.py``.
 """
 
 from __future__ import annotations

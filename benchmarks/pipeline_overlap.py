@@ -34,6 +34,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     EC2_CATALOG,
     EC2_CATALOG_ADJUSTED,
@@ -244,6 +245,7 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="reduced budgets for tier-1 CI")
     args = ap.parse_args()
+    enable_compile_cache()
     res = pipeline_overlap(smoke=args.smoke)
     print(json.dumps(res, indent=2))
     raise SystemExit(0 if res["ok"] else 1)

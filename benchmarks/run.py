@@ -10,6 +10,7 @@ import argparse
 import sys
 
 import repro.telemetry as telemetry
+from repro.compile_cache import enable_compile_cache
 
 from . import blended_workloads, container_sizing, dnn_annealing, \
     fleet_arbitration, kernel_bench, paper_figures, pipeline_overlap, \
@@ -35,6 +36,7 @@ def main(argv=None) -> int:
     ap.add_argument("--only", nargs="*", default=None,
                     help="suite names to run (default: all)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     results = []
     for name, fn in SUITES.items():

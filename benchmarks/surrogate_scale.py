@@ -31,6 +31,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     EC2_CATALOG_ADJUSTED,
     HIBENCH_JOBS,
@@ -424,6 +425,7 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="reduced budgets for tier-1 CI")
     args = ap.parse_args()
+    enable_compile_cache()
     res = surrogate_scale(smoke=args.smoke)
     print(json.dumps(res, indent=2))
     raise SystemExit(0 if res["ok"] else 1)

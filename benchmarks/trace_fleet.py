@@ -30,6 +30,7 @@ import time
 import numpy as np
 
 import repro.telemetry as telemetry
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     EC2_CATALOG_ADJUSTED,
     Objective,
@@ -296,6 +297,7 @@ if __name__ == "__main__":
                          "telemetry-armed baseline leg (nightly gate; the "
                          "injected budget-cut leg is exempt by design)")
     args = ap.parse_args()
+    enable_compile_cache()
     out = trace_fleet(smoke=args.smoke)
     print(json.dumps([out], indent=2))
     if args.fail_on_alerts:

@@ -552,6 +552,16 @@ def chain_bucket(n: int, multiple: int = 1) -> int:
     return p
 
 
+def chain_rows(C: int, mesh=None, bucket: bool = True) -> int:
+    """Rows :func:`fleet_chains` runs for ``C`` chains: the
+    :func:`chain_bucket` (rounded to the mesh's device count) or, unbucketed,
+    ``C`` rounded up to that count."""
+    n_dev = 1 if mesh is None else int(mesh.devices.size)
+    if bucket:
+        return chain_bucket(C, n_dev)
+    return -(-C // n_dev) * n_dev
+
+
 def _pad_chains(a: np.ndarray, p: int) -> np.ndarray:
     """Pad axis 0 from C to ``p`` by repeating row 0 (valid chain data —
     the padding chains run and are sliced away; per-chain independence of
@@ -572,7 +582,6 @@ def _fleet_shard_jit(mesh, shape, categorical, noise_std, has_valid,
     precomputed ``extra`` rows), so the math is embarrassingly parallel
     and the single-device instance is bit-identical to the direct
     :func:`_fleet_nd_jit` dispatch — the parity tests pin that."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     row = PartitionSpec("tenants")
@@ -596,13 +605,13 @@ def _fleet_shard_jit(mesh, shape, categorical, noise_std, has_valid,
             one, in_axes=(0, 0, 0, 0, 0 if has_extra else None),
         )(keys, taus, inits, y_flat, extra)
 
-    body = shard_map(
+    body = jax.shard_map(
         run, mesh=mesh,
         in_specs=(row, row, row, row)
         + ((rep,) if has_valid else ())
         + ((row,) if has_extra else ()),
         out_specs=(row, row, row),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(body)
 
 
@@ -630,17 +639,18 @@ def fleet_chains(
     the direct :func:`_fleet_nd_jit` dispatch of the historical fleet hot
     path; with a mesh, chains run under ``shard_map`` over the mesh's
     ``"tenants"`` axis — bit-identical per chain (chains are independent;
-    the parity tests enforce it).  Returns ``(states, ys, accepts)``
-    sliced back to the true C.
+    the parity tests enforce it).  Returns ``(states, ys, accepts)`` for
+    all :func:`chain_rows` rows; rows C.. are padding (chain 0 again), for
+    the caller to drop once the arrays are on the host.
+
+    Every device op whose shape follows C compiles once per distinct C,
+    and a churning fleet sees a new C nearly every round: so the outputs
+    are not sliced here, and a caller that passes keys already padded to
+    :func:`chain_rows` rows (from row-0-padded ids, as the fleet does)
+    runs nothing but the bucket-shaped kernel.
     """
     C = int(np.shape(tables)[0])
-    n_dev = 1 if mesh is None else int(mesh.devices.size)
-    if bucket:
-        P = chain_bucket(C, n_dev)
-    elif C % n_dev:
-        P = ((C + n_dev - 1) // n_dev) * n_dev
-    else:
-        P = C
+    P = chain_rows(C, mesh, bucket)
     # keys are already device-resident: pad by repeating row 0 with jnp
     # (the np.asarray route would pull the key data to host — the fleet's
     # only per-round device->host transfer besides the result read-back)
@@ -671,7 +681,7 @@ def fleet_chains(
             valid_flat, taus_p, init_p, ext_p, shape=tuple(shape),
             categorical=tuple(categorical), dynamic=False,
             noise_std=float(noise_std), per_chain=True)
-    return st[:C], ys[:C], acc[:C]
+    return st, ys, acc
 
 
 def _default_init(enc: EncodedSpace) -> np.ndarray:

@@ -43,7 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .annealing import _fleet_nd_jit, chain_accept_stats, fleet_chains
+from .annealing import _fleet_nd_jit, _pad_chains, chain_accept_stats, \
+    chain_rows, fleet_chains
 from .change_detect import BatchedPageHinkley
 from .instrumentation import note_round
 from ..telemetry import provenance
@@ -619,7 +620,11 @@ class FleetController(ControllerMixin):
             inits = np.stack(
                 np.unravel_index(self._incumbents[active], self._shape),
                 axis=-1).astype(np.int32)
-            keys = self._chain_keys(r, self._stream_ids[active])
+            # keys for the padded chain rows (padding repeats row 0, as
+            # fleet_chains would) so no device op takes a shape from A
+            P = chain_rows(A, self.mesh, self.chain_bucketing)
+            keys = self._chain_keys(
+                r, _pad_chains(self._stream_ids[active], P))
             # active chains run through fleet_chains: bucket-padded to a
             # handful of compiled shapes (churning tenant counts stop
             # retracing) and, with a mesh, shard_map'd over tenant blocks
@@ -633,8 +638,10 @@ class FleetController(ControllerMixin):
 
             # one consolidated pull for the round: states, objectives and
             # accept flags come back in a single device_get (1 transfer)
-            # instead of three independent np.asarray coercions
-            st_h, ys_h, accepts = jax.device_get((st, ys_d, acc_d))
+            # instead of three independent np.asarray coercions; the
+            # padding rows are dropped here, on the host
+            st_h, ys_h, accepts = (a[:A] for a in jax.device_get(
+                (st, ys_d, acc_d)))
 
             # proposals: best visited state (step-0 incumbent included)
             # under the penalized objective

@@ -165,6 +165,7 @@ class SizingSpace:
         """The un-jitted batched scoring closure shared by
         :attr:`_eval_jit` (caller-supplied candidates) and
         :attr:`_table_jit` (in-trace full-grid enumeration)."""
+        import jax
         import jax.numpy as jnp
 
         from ..kernels import ops as kernel_ops
@@ -199,7 +200,9 @@ class SizingSpace:
                             jnp.inf)
             mu = jnp.minimum(mu, cap)
             repl = repl_menu[repl_idx]
-            lam = rates @ visits                                   # (K,)
+            # full f32: the TPU's default matmul rounds rates to bfloat16
+            lam = jnp.matmul(rates, visits,                        # (K,)
+                             precision=jax.lax.Precision.HIGHEST)
             B = cand.shape[0]
             # fold classes into rows (row b*C + c) so one kernel pass
             # yields every class's critical path

@@ -86,12 +86,14 @@ def quantize_int8_ref(x):
 
 
 def pairwise_sqdist_ref(xq, xm):
-    """xq (Q, F), xm (M, F) -> (Q, M) squared Euclidean distances."""
+    """xq (Q, F), xm (M, F) -> (Q, M) squared Euclidean distances, with
+    full-f32 matmuls (the TPU's default precision rounds to bfloat16)."""
     xq = xq.astype(jnp.float32)
     xm = xm.astype(jnp.float32)
     qq = jnp.sum(xq * xq, axis=1, keepdims=True)
     mm = jnp.sum(xm * xm, axis=1, keepdims=True)
-    return jnp.maximum(qq + mm.T - 2.0 * (xq @ xm.T), 0.0)
+    g = jnp.matmul(xq, xm.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.maximum(qq + mm.T - 2.0 * g, 0.0)
 
 
 def fused_interp_ref(xq, xm, y, w_rec, *, kind: str = "idw",
@@ -118,7 +120,8 @@ def fused_interp_ref(xq, xm, y, w_rec, *, kind: str = "idw",
     wsum = k.sum(axis=1)
     fallback = (y32 * w32).sum() / jnp.maximum(w32.sum(), 1e-12)
     mean = jnp.where(wsum > 1e-12,
-                     (k @ y32) / jnp.maximum(wsum, 1e-12), fallback)
+                     jnp.matmul(k, y32, precision=jax.lax.Precision.HIGHEST)
+                     / jnp.maximum(wsum, 1e-12), fallback)
     dmin = jnp.sqrt(d2.min(axis=1))
     return mean, dmin
 

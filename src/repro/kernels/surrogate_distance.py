@@ -28,6 +28,11 @@ from jax.experimental import pallas as pl
 # padding rows sit at this coordinate so their distances are huge without
 # needing a separate mask input.
 _PAD_SENTINEL = 1e4
+# Full-f32 MXU passes: at the default precision the TPU rounds both
+# operands to bfloat16, which moves distances by ~0.1 at unit-scale
+# features and flips RBF estimates (measured on a v5e against the f32
+# reference); the interpret path on the CPU is f32 either way.
+_F32_DOT = jax.lax.Precision.HIGHEST
 
 
 def _sqdist_kernel(q_ref, m_ref, out_ref):
@@ -36,7 +41,7 @@ def _sqdist_kernel(q_ref, m_ref, out_ref):
     qq = jnp.sum(q * q, axis=1, keepdims=True)    # (block_q, 1)
     mm = jnp.sum(m * m, axis=1, keepdims=True)    # (block_m, 1)
     g = jax.lax.dot_general(
-        q, m, (((1,), (1,)), ((), ())),
+        q, m, (((1,), (1,)), ((), ())), precision=_F32_DOT,
         preferred_element_type=jnp.float32)       # (block_q, block_m)
     out_ref[...] = jnp.maximum(qq + mm.T - 2.0 * g, 0.0)
 
@@ -93,7 +98,7 @@ def _fused_interp_kernel(q_ref, m_ref, yw_ref, mean_ref, dmin_ref, *,
     qq = jnp.sum(q * q, axis=1, keepdims=True)    # (bq, 1)
     mm = jnp.sum(m * m, axis=1)                   # (Mp,)
     g = jax.lax.dot_general(
-        q, m, (((1,), (1,)), ((), ())),
+        q, m, (((1,), (1,)), ((), ())), precision=_F32_DOT,
         preferred_element_type=jnp.float32)       # (bq, Mp)
     d2 = jnp.maximum(qq + mm[None, :] - 2.0 * g, 0.0)
     if kind == "rbf":

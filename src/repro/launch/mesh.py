@@ -16,23 +16,12 @@ DCN boundary — exactly one gradient reduction crosses it per step.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit Auto/Explicit/Manual axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax (e.g. 0.4.x): every axis is implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def mesh_axis_kwargs(n_axes: int) -> dict:
-    """``axis_types=`` kwargs for :func:`jax.make_mesh`, feature-detected.
-
-    On jax builds without ``jax.sharding.AxisType`` returns ``{}`` — those
-    versions treat every mesh axis as Auto, which is exactly what we ask
-    for on newer builds.
-    """
-    if AxisType is None:
-        return {}
+    """``axis_types=`` kwargs for :func:`jax.make_mesh`: every axis Auto
+    (the compiler partitions; no explicit-sharding types)."""
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 

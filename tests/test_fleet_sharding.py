@@ -14,7 +14,7 @@ from repro.core import (
     fleet_chains,
     make_ec2_space,
 )
-from repro.core.annealing import _fleet_nd_jit
+from repro.core.annealing import _fleet_nd_jit, chain_rows
 from repro.core.costmodel import SimulatedEvaluator
 from repro.launch.mesh import make_tenant_mesh
 
@@ -95,7 +95,7 @@ def test_fleet_chains_matches_direct_kernel():
                           shape=shape, categorical=(False,), mesh=None,
                           bucket=True)
     for a, b in zip(direct, routed):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b)[:6])
 
 
 def test_fleet_chains_shard_map_bit_identical():
@@ -108,20 +108,51 @@ def test_fleet_chains_shard_map_bit_identical():
                            shape=shape, categorical=(False,), mesh=mesh,
                            bucket=True)
     for a, b in zip(plain, sharded):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b)[:7])
 
 
 def test_fleet_chains_padding_invariant():
     """Bucket padding must not perturb the real chains: C=5 padded to 8
-    returns rows identical to the unpadded run."""
+    returns rows identical to the unpadded run, and the padding rows
+    repeat chain 0 (its inputs and its key)."""
     keys, tables, taus, inits, extra, shape = _chain_inputs(C=5)
+    assert chain_rows(5) == 8 and chain_rows(5, bucket=False) == 5
     padded = fleet_chains(keys, tables, None, taus, inits, extra,
                           shape=shape, categorical=(False,), bucket=True)
     plain = fleet_chains(keys, tables, None, taus, inits, extra,
                          shape=shape, categorical=(False,), bucket=False)
     for a, b in zip(padded, plain):
-        assert np.asarray(a).shape[0] == 5
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        a = np.asarray(a)
+        assert a.shape[0] == 8 and np.asarray(b).shape[0] == 5
+        np.testing.assert_array_equal(a[:5], np.asarray(b))
+        np.testing.assert_array_equal(a[5:], np.repeat(a[:1], 3, axis=0))
+
+
+def test_active_count_within_a_bucket_lowers_no_program():
+    """A new active-tenant count inside a chain bucket already seen must
+    reuse the compiled programs: no device op may take its shape from the
+    count (on a TPU each such op costs a fresh compile every round)."""
+    lowered = []
+
+    def on_event(event, secs, **_):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(event)
+
+    ctl = _controller(incremental=True, settle_rounds=1, detectors=False)
+    ctl.run(2)
+    counts = []
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        for n in (3, 4):              # both in chain bucket 4
+            for t in ctl.tenants[:n]:
+                ctl.retune_tenant(t.name, dict(ctl.tenants[-1].blend))
+            del lowered[:]
+            ctl.round()
+            assert ctl.last_annealed == n
+            counts.append(len(lowered))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert counts[1] == 0, counts
 
 
 # ---------------------------------------------------------------------------

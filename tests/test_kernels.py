@@ -1,5 +1,7 @@
-"""Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles
-(interpret mode on CPU; the same kernels lower for the TPU target)."""
+"""Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles,
+in interpret mode on the CPU.  Compiling for the TPU target is checked by
+``tests/test_tpu_compile.py``; ``chip_smoke.py`` runs the main-path
+kernels on the chip against the same oracles."""
 
 import jax
 import jax.numpy as jnp
