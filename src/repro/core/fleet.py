@@ -628,8 +628,7 @@ class FleetController(ControllerMixin):
             # active chains run through fleet_chains: bucket-padded to a
             # handful of compiled shapes (churning tenant counts stop
             # retracing) and, with a mesh, shard_map'd over tenant blocks
-            with span("fleet.anneal", cat="fleet",
-                      metric="fleet/anneal_s"):
+            with span("fleet.anneal", cat="fleet"):
                 st, ys_d, acc_d = fleet_chains(
                     keys, tables_mat[active],
                     self._valid_jnp, taus, inits, rows[active],
@@ -639,9 +638,11 @@ class FleetController(ControllerMixin):
             # one consolidated pull for the round: states, objectives and
             # accept flags come back in a single device_get (1 transfer)
             # instead of three independent np.asarray coercions; the
-            # padding rows are dropped here, on the host
-            st_h, ys_h, accepts = (a[:A] for a in jax.device_get(
-                (st, ys_d, acc_d)))
+            # padding rows are dropped here, on the host; the round's one
+            # wait for the device
+            with span("fleet.sync", cat="fleet"):
+                st_h, ys_h, accepts = (a[:A] for a in jax.device_get(
+                    (st, ys_d, acc_d)))
 
             # proposals: best visited state (step-0 incumbent included)
             # under the penalized objective

@@ -605,40 +605,41 @@ class SizingController(ControllerMixin):
             reheated = True
         taus = self._schedule.tau_array(n0, self.steps_per_round)
 
-        key_r = jax.random.fold_in(self._key, r)
-        k_init, k_run = jax.random.split(key_r)
-
         if self.device_loop:
             import jax.numpy as jnp
 
             # device-resident phase: fused table -> anneal -> top-K
             # without a bulk host round-trip; only the (topk, ndim)
             # decision packet is read back
-            with span("sizing.refit", cat="sizing"):
-                table_d = self._dtable_for(rates)
-            inits_d = random_valid_states(
-                k_init, self._enc, self.n_chains).astype(jnp.int32)
-            inits_d = inits_d.at[0].set(
-                jnp.asarray(self.incumbent, jnp.int32))
-            with span("sizing.anneal", cat="sizing",
-                      metric="sizing/anneal_s"):
-                out = anneal_fleet(
-                    k_run, self._enc, table_d.reshape(self._shape),
-                    self.steps_per_round,
-                    jnp.broadcast_to(
-                        jnp.asarray(taus, jnp.float32),
-                        (self.n_chains, self.steps_per_round)),
-                    inits=inits_d, n_chains=self.n_chains)
-            sel, explored_d = _sizing_select_jit(
-                self._shape, self.measure_topk)(
-                inits_d, out["states"], table_d, out["ys"],
-                out["accepts"])
+            with span("sizing.dispatch", cat="sizing"):
+                key_r = jax.random.fold_in(self._key, r)
+                k_init, k_run = jax.random.split(key_r)
+                with span("sizing.refit", cat="sizing"):
+                    table_d = self._dtable_for(rates)
+                inits_d = random_valid_states(
+                    k_init, self._enc, self.n_chains).astype(jnp.int32)
+                inits_d = inits_d.at[0].set(
+                    jnp.asarray(self.incumbent, jnp.int32))
+                with span("sizing.anneal", cat="sizing"):
+                    out = anneal_fleet(
+                        k_run, self._enc, table_d.reshape(self._shape),
+                        self.steps_per_round,
+                        jnp.broadcast_to(
+                            jnp.asarray(taus, jnp.float32),
+                            (self.n_chains, self.steps_per_round)),
+                        inits=inits_d, n_chains=self.n_chains)
+                sel, explored_d = _sizing_select_jit(
+                    self._shape, self.measure_topk)(
+                    inits_d, out["states"], table_d, out["ys"],
+                    out["accepts"])
             # .tolist()/bool() read the small decision packet — the one
             # host pull of the round, below the sanitizer's bulk-transfer
-            # accounting (np.asarray / device_get)
-            explored = bool(explored_d)
-            cand_idx = [tuple(int(v) for v in row)
-                        for row in sel.tolist() if row[0] >= 0]
+            # accounting (np.asarray / device_get), and the only place
+            # the round waits for the device
+            with span("sizing.sync", cat="sizing"):
+                explored = bool(explored_d)
+                cand_idx = [tuple(int(v) for v in row)
+                            for row in sel.tolist() if row[0] >= 0]
             if provenance.get() is not None:
                 # armed-only audit pulls (not on the steady-state path)
                 inits = np.asarray(inits_d)
@@ -653,14 +654,15 @@ class SizingController(ControllerMixin):
                         axis=1).reshape(-1, self._enc.ndim).T),
                     self._shape)
         else:
+            key_r = jax.random.fold_in(self._key, r)
+            k_init, k_run = jax.random.split(key_r)
             with span("sizing.refit", cat="sizing"):
                 table = self._table_for(rates)
             inits = np.array(
                 random_valid_states(k_init, self._enc, self.n_chains),
                 np.int32)
             inits[0] = np.asarray(self.incumbent, np.int32)
-            with span("sizing.anneal", cat="sizing",
-                      metric="sizing/anneal_s"):
+            with span("sizing.anneal", cat="sizing"):
                 out = anneal_fleet(
                     k_run, self._enc,
                     table.reshape(self._shape).astype(np.float32),
@@ -669,8 +671,10 @@ class SizingController(ControllerMixin):
                                     (self.n_chains, self.steps_per_round)),
                     inits=inits, n_chains=self.n_chains)
 
+            with span("sizing.sync", cat="sizing"):
+                states = np.asarray(out["states"])
             visited = np.concatenate(
-                [inits[:, None, :], np.asarray(out["states"])],
+                [inits[:, None, :], states],
                 axis=1).reshape(-1, self._enc.ndim)
             flat = np.ravel_multi_index(tuple(visited.T), self._shape)
 
@@ -703,42 +707,43 @@ class SizingController(ControllerMixin):
                         for f in cand]
         with span("sizing.measure", cat="sizing"):
             results = self._measure_candidates(cand_idx, rates)
-        self._count_measures(len(results))
-        if self.recycle_store is not None:
-            for st, rr in zip(cand_idx, results):
-                self.recycle_store.add(st, float(rr["y"]), float(r))
-        k_best = int(np.argmin([rr["y"] for rr in results]))
-        prev = self.incumbent
-        self.incumbent = cand_idx[k_best]
-        decoded = self.space.decode(self.incumbent)
-        res = results[k_best]
-        y = float(res["y"])
-        if self._detector is not None and self._detector.update(y):
-            self._reheat_pending = True
+        with span("sizing.commit", cat="sizing"):
+            self._count_measures(len(results))
+            if self.recycle_store is not None:
+                for st, rr in zip(cand_idx, results):
+                    self.recycle_store.add(st, float(rr["y"]), float(r))
+            k_best = int(np.argmin([rr["y"] for rr in results]))
+            prev = self.incumbent
+            self.incumbent = cand_idx[k_best]
+            decoded = self.space.decode(self.incumbent)
+            res = results[k_best]
+            y = float(res["y"])
+            if self._detector is not None and self._detector.update(y):
+                self._reheat_pending = True
 
-        m = Measurement(
-            exec_time_s=float(res["penalized_latency"]),
-            cost_usd=float(res["cost"]),
-            slo_violated=bool(res["slo_attainment"] < 1.0))
-        counts = self.evaluation_counts()
-        d = SizingDecision(
-            n=r, job="mix", config=ClusterConfig(
-                self.family, n_workers=self.spec.total_cores(decoded)),
-            measurement=m, y=y, accepted=bool(self.incumbent != prev),
-            explored=explored, tau=float(taus[-1]), reheated=reheated,
-            sizing=decoded, mix=dict(rates),
-            usd_per_hr=float(res["cost"]),
-            slo_attainment=float(res["slo_attainment"]),
-            true_measures=counts["true_measures"],
-            surrogate_queries=counts["surrogate_queries"],
-        )
-        self.decisions.append(d)
-        if provenance.get() is not None:
-            self._record_round_provenance(
-                r, d, res, results, cand_idx, k_best, prev, rates,
-                ys, accepts, y0, taus, flat)
-        self._round += 1
-        note_round("SizingController", self)
+            m = Measurement(
+                exec_time_s=float(res["penalized_latency"]),
+                cost_usd=float(res["cost"]),
+                slo_violated=bool(res["slo_attainment"] < 1.0))
+            counts = self.evaluation_counts()
+            d = SizingDecision(
+                n=r, job="mix", config=ClusterConfig(
+                    self.family, n_workers=self.spec.total_cores(decoded)),
+                measurement=m, y=y, accepted=bool(self.incumbent != prev),
+                explored=explored, tau=float(taus[-1]), reheated=reheated,
+                sizing=decoded, mix=dict(rates),
+                usd_per_hr=float(res["cost"]),
+                slo_attainment=float(res["slo_attainment"]),
+                true_measures=counts["true_measures"],
+                surrogate_queries=counts["surrogate_queries"],
+            )
+            self.decisions.append(d)
+            if provenance.get() is not None:
+                self._record_round_provenance(
+                    r, d, res, results, cand_idx, k_best, prev, rates,
+                    ys, accepts, y0, taus, flat)
+            self._round += 1
+            note_round("SizingController", self)
         return d
 
     def _record_round_provenance(self, r, d, res, results, cand_idx,

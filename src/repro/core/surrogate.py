@@ -1199,8 +1199,7 @@ class SurrogateAnnealer:
             xq = self._window_feats(sub, offs)
             mb = min(_bucket(len(self.store)), self._dstore.cap)
             xm, ys_d, rec_d = self._dstore.refit_view(t, mb)
-            with span("surrogate.refit", cat="surrogate",
-                      metric="surrogate/refit_s"):
+            with span("surrogate.refit", cat="surrogate"):
                 mean_q, dmin_q = _interp_jit(self.model.kind)(
                     xq, xm, ys_d, rec_d, self.model.length_scale,
                     self.model.idw_power, self.model.eps)
@@ -1217,8 +1216,7 @@ class SurrogateAnnealer:
             bonus = jnp.broadcast_to(
                 (-self.kappa * unc_w).astype(jnp.float32)[None, :],
                 (self.n_chains, W))
-            with span("surrogate.anneal", cat="surrogate",
-                      metric="surrogate/anneal_s"):
+            with span("surrogate.anneal", cat="surrogate"):
                 out = anneal_fleet(
                     k_run, enc, mean_w.reshape(sub.shape),
                     self.steps_per_round, self.tau, inits=inits_d,
@@ -1229,8 +1227,10 @@ class SurrogateAnnealer:
                 jnp.float32(self.kappa), jnp.float32(self._best(t)[1]))
             # .tolist() reads the m*ndim-int decision packet — the one
             # host pull of the round, below the sanitizer's bulk-transfer
-            # accounting (np.asarray / device_get)
-            rows = sel.tolist()
+            # accounting (np.asarray / device_get), and its one wait for
+            # the device
+            with span("surrogate.sync", cat="surrogate"):
+                rows = sel.tolist()
             with span("surrogate.measure", cat="surrogate"):
                 measured.extend(self._measure_states(
                     [tuple(int(v) + int(o) for v, o in zip(r, offs))
@@ -1251,8 +1251,7 @@ class SurrogateAnnealer:
             inits[0] = np.asarray(self.incumbent, np.int64) - offs
             bonus = np.broadcast_to((-self.kappa * unc).astype(np.float32),
                                     (self.n_chains, W))
-            with span("surrogate.anneal", cat="surrogate",
-                      metric="surrogate/anneal_s"):
+            with span("surrogate.anneal", cat="surrogate"):
                 out = anneal_fleet(
                     k_run, enc, mean.reshape(sub.shape).astype(np.float32),
                     self.steps_per_round, self.tau, inits=inits,

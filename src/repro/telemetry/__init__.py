@@ -6,7 +6,10 @@ Three pieces, one switch:
   series / histograms behind guarded module functions (``inc`` /
   ``record`` / ``observe`` / ``set_gauge``);
 * :mod:`repro.telemetry.spans` — nested wall-clock phase spans with
-  Chrome/Perfetto ``trace_event`` export;
+  Chrome/Perfetto ``trace_event`` export, mirrored into the JAX profiler
+  as ``TraceAnnotation`` events while armed (run the controllers under
+  ``REPRO_TELEMETRY=1`` and ``jax.profiler.trace(dir)`` to see their
+  phases on the host line above the device ops of the ``.xplane.pb``);
 * :mod:`repro.telemetry.report` — JSON snapshots plus the
   ``python -m repro.telemetry.report`` terminal dashboard;
 * :mod:`repro.telemetry.provenance` — flight recorder of per-round,

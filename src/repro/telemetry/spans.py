@@ -19,6 +19,22 @@ arbitrate / ledger phase tree of every controller round.  ``metric=``
 additionally funnels each span's duration (seconds) into a
 :mod:`repro.telemetry.registry` histogram of that name — one code site
 feeds both the trace and the dashboard.
+
+Profiler mirror: while a recorder is attached, each span also enters a
+``jax.profiler.TraceAnnotation`` of the same name (looked up once, at
+:func:`enable`; without jax, none is made).  Under a running profile —
+``REPRO_TELEMETRY=1`` plus ``jax.profiler.trace(dir)`` — the controller's
+phases then appear in the ``.xplane.pb`` on the host thread's line, on
+the device trace's clock and with their nesting, directly above the
+device ops they dispatched or waited for.
+
+Naming: a controller's phases are ``<controller>.<phase>``; the span
+around the round's blocking device read is ``<controller>.sync``
+(``sizing.sync``, ``fleet.sync``, ``surrogate.sync``), so every wait on
+the device has one name.  Spans that only enqueue device work
+(``*.anneal``, the device-path ``*.refit``) close before the device
+finishes it; the round's wait for that work falls in the ``.sync`` that
+follows.
 """
 
 from __future__ import annotations
@@ -38,6 +54,16 @@ __all__ = [
 # One process-wide monotonic epoch so events from every thread share a
 # timeline; Perfetto wants microseconds from an arbitrary origin.
 _T0 = time.perf_counter()
+
+
+def _profiler_annotation() -> type | None:
+    """``jax.profiler.TraceAnnotation``, or None where jax cannot be
+    imported (the telemetry layer itself never needs jax)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 class SpanRecorder:
@@ -168,20 +194,25 @@ _NULL_SPAN = _NullSpan()
 
 class _Span:
     """Live span handle; records into the recorder (and optionally a
-    duration histogram) on exit."""
+    duration histogram) on exit, and mirrors itself into the profiler
+    when given an annotation class."""
 
     __slots__ = ("_name", "_cat", "_metric", "_args", "_rec", "_t0",
-                 "_depth")
+                 "_depth", "_ann")
 
     def __init__(self, name: str, cat: str, metric: str | None,
-                 args: dict | None, rec: "SpanRecorder | None"):
+                 args: dict | None, rec: "SpanRecorder | None",
+                 annotation: type | None):
         self._name = name
         self._cat = cat
         self._metric = metric
         self._args = args
         self._rec = rec
+        self._ann = annotation(name) if annotation is not None else None
 
     def __enter__(self) -> "_Span":
+        if self._ann is not None:
+            self._ann.__enter__()
         rec = self._rec
         if rec is not None:
             stack = rec._depth_stack()
@@ -192,6 +223,8 @@ class _Span:
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         rec = self._rec
         if rec is not None:
             rec._depth_stack().pop()
@@ -203,19 +236,24 @@ class _Span:
 
 
 _RECORDER: SpanRecorder | None = None
+# the profiler annotation class live spans mirror into; set iff a
+# recorder is attached (and jax importable)
+_ANNOTATION: type | None = None
 
 
 def enable(recorder: SpanRecorder | None = None) -> SpanRecorder:
-    """Attach ``recorder`` (or a fresh one) as the process span sink.
-    Prefer ``repro.telemetry.enable()``, which arms metrics too."""
-    global _RECORDER
+    """Attach ``recorder`` (or a fresh one) as the process span sink, and
+    look up the profiler annotation its spans mirror into.  Prefer
+    ``repro.telemetry.enable()``, which arms metrics too."""
+    global _RECORDER, _ANNOTATION
+    _ANNOTATION = _profiler_annotation()
     _RECORDER = recorder if recorder is not None else SpanRecorder()
     return _RECORDER
 
 
 def disable() -> SpanRecorder | None:
-    global _RECORDER
-    prev, _RECORDER = _RECORDER, None
+    global _RECORDER, _ANNOTATION
+    prev, _RECORDER, _ANNOTATION = _RECORDER, None, None
     return prev
 
 
@@ -227,15 +265,16 @@ def span(name: str, cat: str = "", metric: str | None = None,
          args: dict | None = None):
     """Context manager timing a phase.
 
-    Records a trace event when a recorder is attached; when ``metric``
-    is given, also observes the duration (seconds) into that metrics
-    histogram whenever a metrics sink is attached.  With neither sink
-    relevant, returns the no-op singleton.
+    Records a trace event, and a profiler annotation of the same name,
+    when a recorder is attached; when ``metric`` is given, also observes
+    the duration (seconds) into that metrics histogram whenever a
+    metrics sink is attached.  With neither sink relevant, returns the
+    no-op singleton.
     """
     rec = _RECORDER
     if rec is None and (metric is None or _registry._SINK is None):
         return _NULL_SPAN
-    return _Span(name, cat, metric, args, rec)
+    return _Span(name, cat, metric, args, rec, _ANNOTATION)
 
 
 def traced(name: str | None = None, cat: str = "",

@@ -498,3 +498,160 @@ def test_maybe_enable_respects_env(monkeypatch):
     assert tel is not None and telemetry.get() is tel
     assert telemetry.maybe_enable() is tel   # idempotent
     telemetry.disable()
+
+
+# ---------------------------------------------------------------------------
+# the profiler mirror and the controllers' sync spans
+# ---------------------------------------------------------------------------
+
+
+def _host_annotations(profile_dir) -> dict[str, list[tuple]]:
+    """name -> [(plane, line, start_ns, end_ns)] of the host events in
+    the newest ``.xplane.pb`` under ``profile_dir``."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(os.path.join(
+        str(profile_dir), "plugins", "profile", "*", "*.xplane.pb")))
+    assert paths, "the profiler wrote no trace"
+    out: dict[str, list[tuple]] = {}
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    (plane.name, line.name, ev.start_ns,
+                     ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_spans_mirror_into_the_profiler_trace(tmp_path):
+    """Armed spans appear in the .xplane.pb on the host thread's line,
+    nested as they ran; dark spans add nothing."""
+    import jax
+
+    with telemetry.session():
+        with jax.profiler.trace(str(tmp_path)):
+            with span("t.outer"):
+                with span("t.inner"):
+                    jax.numpy.ones(4).block_until_ready()
+                with span("t.after"):
+                    pass
+    ann = _host_annotations(tmp_path)
+    (outer,), (inner,), (after,) = (ann["t.outer"], ann["t.inner"],
+                                    ann["t.after"])
+    assert outer[:2] == inner[:2] == after[:2]       # one plane and line
+    for child in (inner, after):
+        assert outer[2] <= child[2] and child[3] <= outer[3]
+    assert inner[3] <= after[2]
+    assert spans_mod._ANNOTATION is None             # detached again
+
+
+def test_telemetry_imports_and_records_without_jax():
+    """repro.telemetry needs no jax: with jax blocked from import it
+    still loads, and armed spans record with no profiler mirror."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "class Block:\n"
+        "    def find_spec(self, name, path=None, target=None):\n"
+        "        if name == 'jax' or name.startswith('jax.'):\n"
+        "            raise ImportError('jax blocked')\n"
+        "sys.meta_path.insert(0, Block())\n"
+        "import repro.telemetry as t\n"
+        "from repro.telemetry import spans\n"
+        "rec = spans.enable()\n"
+        "assert spans._ANNOTATION is None\n"
+        "with t.span('a'):\n"
+        "    pass\n"
+        "assert [s[0] for s in rec.spans()] == ['a']\n"
+        "assert 'jax' not in sys.modules\n"
+        "print('ok')\n")
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    env.pop("REPRO_TELEMETRY", None)
+    res = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert res.returncode == 0, res.stderr
+    assert res.stdout.strip() == "ok"
+
+
+def _children(recs, parent: str, depth: int) -> list[list[str]]:
+    """For each ``parent`` span, the names of the spans ``depth`` levels
+    below it that lie inside it, in start order."""
+    out = []
+    for p in (r for r in recs if r[0] == parent):
+        lo, hi = p[2], p[2] + p[3]
+        kids = sorted((r for r in recs
+                       if r[5] == p[5] + depth and r[4] == p[4]
+                       and lo <= r[2] and r[2] + r[3] <= hi),
+                      key=lambda r: r[2])
+        out.append([r[0] for r in kids])
+    return out
+
+
+def test_sizing_round_is_tiled_by_dispatch_sync_measure_commit():
+    with telemetry.session() as tel:
+        _sizing().run(3)
+    recs = tel.spans.spans()
+    assert _children(recs, "sizing.round", 1) == [
+        ["sizing.dispatch", "sizing.sync", "sizing.measure",
+         "sizing.commit"]] * 3
+    # refit and anneal only enqueue: they stay inside the dispatch
+    assert _children(recs, "sizing.dispatch", 1) == [
+        ["sizing.refit", "sizing.anneal"]] * 3
+
+
+def test_sizing_host_path_waits_in_its_sync_span():
+    ctl = _sizing()
+    ctl.device_loop = False
+    with telemetry.session() as tel:
+        ctl.run(2)
+    assert _children(tel.spans.spans(), "sizing.round", 1) == [
+        ["sizing.refit", "sizing.anneal", "sizing.sync", "sizing.measure",
+         "sizing.commit"]] * 2
+
+
+@pytest.mark.parametrize("device_loop", [True, False])
+def test_sizing_decisions_identical_armed_and_dark(device_loop):
+    """The spans reorder no device work and draw no key: the same seeded
+    controller commits bit-identical decisions armed and dark."""
+
+    def run(armed: bool):
+        ctl = _sizing()
+        ctl.device_loop = device_loop
+        if armed:
+            with telemetry.session():
+                ds = ctl.run(6)
+        else:
+            ds = ctl.run(6)
+        return [(d.n, tuple(sorted(d.sizing.items())), d.y, d.explored,
+                 d.accepted, d.tau, d.reheated) for d in ds]
+
+    assert run(armed=True) == run(armed=False)
+
+
+def test_fleet_and_surrogate_wait_in_their_sync_spans():
+    with telemetry.session() as tel:
+        _fleet().round()
+        _surrogate().run(2)
+    recs = tel.spans.spans()
+    assert _children(recs, "fleet.round", 1) == [
+        ["fleet.refit", "fleet.anneal", "fleet.sync", "fleet.detect",
+         "fleet.arbitrate", "fleet.ledger", "fleet.measure"]]
+    assert _children(recs, "surrogate.round", 1) == [
+        ["surrogate.refit", "surrogate.anneal", "surrogate.sync",
+         "surrogate.measure"]] * 2
+    # no histogram times an enqueue under an anneal or refit name
+    hists = tel.metrics.snapshot()["histograms"]
+    assert "fleet/measure_s" in hists
+    for name in ("fleet/anneal_s", "sizing/anneal_s", "surrogate/anneal_s",
+                 "surrogate/refit_s"):
+        assert name not in hists
