@@ -164,67 +164,78 @@ class SizingSpace:
     def _eval_body(self):
         """The un-jitted batched scoring closure shared by
         :attr:`_eval_jit` (caller-supplied candidates) and
-        :attr:`_table_jit` (in-trace full-grid enumeration)."""
+        :attr:`_table_jit` (in-trace full-grid enumeration).
+
+        ``run(size_idx, repl_idx, rates, use_kernel)`` takes each state's
+        menu indices as (K, B) int arrays, states on the last axis (the
+        kernel's lanes), and returns ``y (B,)``, ``latency (C, B)``,
+        ``cost (B,)`` and ``slo_attainment (B,)``."""
         import jax
         import jax.numpy as jnp
 
         from ..kernels import ops as kernel_ops
-        from ..kernels.ref import sizing_latency_ref
+        from ..kernels.ref import sizing_entry_latency_ref
 
         dag = self.dag
-        K, C = dag.n_tiers, len(dag.classes)
-        cpu_menu = jnp.asarray([s.cpu for s in self.sizes], jnp.float32)
-        mem_menu = jnp.asarray([s.mem_gb for s in self.sizes], jnp.float32)
-        repl_menu = jnp.asarray(self.replica_counts, jnp.float32)
-        base = jnp.asarray([t.base_rate for t in dag.tiers], jnp.float32)
-        cpu_ref = jnp.asarray([t.cpu_ref for t in dag.tiers], jnp.float32)
-        gamma = jnp.asarray([t.gamma for t in dag.tiers], jnp.float32)
-        mem_rps = jnp.asarray([t.mem_per_rps_gb for t in dag.tiers],
-                              jnp.float32)
-        visits = jnp.asarray(dag.visit_matrix(), jnp.float32)      # (C, K)
-        adj = jnp.asarray(dag.adjacency())
-        entries = jnp.asarray(dag.entry_indices(), jnp.int32)
+        K = dag.n_tiers
+        cpu = np.asarray([s.cpu for s in self.sizes], np.float64)
+        mem = np.asarray([s.mem_gb for s in self.sizes], np.float64)
+        # per-(tier, size) service rate: the tier's curve, capped by what
+        # the container's memory serves
+        mu_menu = np.stack([
+            np.minimum(t.base_rate * (cpu / t.cpu_ref) ** t.gamma,
+                       mem / t.mem_per_rps_gb if t.mem_per_rps_gb > 0
+                       else np.inf)
+            for t in dag.tiers])                                   # (K, S)
+        cpu_menu = np.broadcast_to(cpu, (K, len(cpu)))
+        repl_menu = np.broadcast_to(
+            np.asarray(self.replica_counts, np.float64),
+            (K, len(self.replica_counts)))
+        visit_m = dag.visit_matrix()                               # (C, K)
+        visits = jnp.asarray(visit_m, jnp.float32)
+        dag_static = {
+            "visits": tuple(map(tuple, visit_m.tolist())),
+            "edges": tuple((dag.index(u), dag.index(v))
+                           for u, v in dag.edges),
+            "entries": tuple(int(e) for e in dag.entry_indices()),
+            "c_max": self.c_max,
+            "sat_s": float(self.sat_s),
+        }
         slos = jnp.asarray([c.slo_s for c in dag.classes], jnp.float32)
-        c_max, sat_s = self.c_max, float(self.sat_s)
         price = float(self.price_per_core_hr)
         lam_cost, slo_pen = float(self.lambda_cost), float(self.slo_penalty)
 
-        def run(cand, rates, use_kernel: bool):
-            size_idx = cand[:, 0::2]                               # (B, K)
-            repl_idx = cand[:, 1::2]
-            cpu = cpu_menu[size_idx]
-            mem = mem_menu[size_idx]
-            mu = base[None, :] * (cpu / cpu_ref[None, :]) ** gamma[None, :]
-            cap = jnp.where(mem_rps[None, :] > 0,
-                            mem / jnp.maximum(mem_rps[None, :], 1e-12),
-                            jnp.inf)
-            mu = jnp.minimum(mu, cap)
-            repl = repl_menu[repl_idx]
+        def menu(table, idx):
+            # table[k, idx[k, b]] by selects, which fuse into their
+            # consumers (a gather is a pass of its own); clamps like
+            # indexing
+            out = jnp.broadcast_to(
+                jnp.asarray(table[:, :1], jnp.float32), idx.shape)
+            for j in range(1, table.shape[1]):
+                out = jnp.where(idx >= j,
+                                jnp.asarray(table[:, j:j + 1], jnp.float32),
+                                out)
+            return out
+
+        def run(size_idx, repl_idx, rates, use_kernel: bool):
+            mu = menu(mu_menu, size_idx)                           # (K, B)
+            repl = menu(repl_menu, repl_idx)
             # full f32: the TPU's default matmul rounds rates to bfloat16
             lam = jnp.matmul(rates, visits,                        # (K,)
                              precision=jax.lax.Precision.HIGHEST)
-            B = cand.shape[0]
-            # fold classes into rows (row b*C + c) so one kernel pass
-            # yields every class's critical path
-            lam_r = jnp.broadcast_to(lam, (B * C, K))
-            mu_r = jnp.repeat(mu, C, axis=0)
-            repl_r = jnp.repeat(repl, C, axis=0)
-            w_r = jnp.tile(visits, (B, 1))
             fn = kernel_ops.sizing_latency if use_kernel \
-                else sizing_latency_ref
-            _, path = fn(lam_r, mu_r, repl_r, w_r, adj,
-                         c_max=c_max, sat_s=sat_s)
-            lat = path.reshape(B, C, K)[:, jnp.arange(C), entries]  # (B, C)
-            cost = (repl * cpu).sum(axis=1) * price
+                else sizing_entry_latency_ref
+            lat = fn(lam, mu, repl, **dag_static)                  # (C, B)
+            cost = (repl * menu(cpu_menu, size_idx)).sum(axis=0) * price
             total = rates.sum()
             shares = jnp.where(total > 0,
                                rates / jnp.maximum(total, 1e-12), 0.0)
-            viol = jnp.maximum(lat - slos[None, :], 0.0)
-            y = ((shares[None, :] * (lat + slo_pen * viol)).sum(axis=1)
+            viol = jnp.maximum(lat - slos[:, None], 0.0)
+            y = ((shares[:, None] * (lat + slo_pen * viol)).sum(axis=0)
                  + lam_cost * cost)
             attain = jnp.where(
                 total > 0,
-                (shares[None, :] * (lat <= slos[None, :])).sum(axis=1),
+                (shares[:, None] * (lat <= slos[:, None])).sum(axis=0),
                 1.0)
             return y, lat, cost, attain
 
@@ -232,17 +243,30 @@ class SizingSpace:
 
     @functools.cached_property
     def _eval_jit(self):
+        """Jitted :attr:`_eval_body` over (B, 2K) candidate index rows;
+        returns ``latency`` as (B, C)."""
         import jax
 
-        return jax.jit(self._eval_body, static_argnames=("use_kernel",))
+        body = self._eval_body
+
+        def evaluate(cand, rates, use_kernel: bool):
+            # (B, 2K) rows of (size, replicas) per tier -> (2, K, B)
+            size_idx, repl_idx = cand.reshape(
+                cand.shape[0], -1, 2).transpose(2, 1, 0)
+            y, lat, cost, attain = body(size_idx, repl_idx, rates,
+                                        use_kernel)
+            return y, lat.T, cost, attain
+
+        return jax.jit(evaluate, static_argnames=("use_kernel",))
 
     @functools.cached_property
     def _table_jit(self):
         """Full-grid objective table in ONE fused trace: candidate
-        enumeration (``jnp.arange`` -> unravel) feeds the Erlang-C +
-        critical-path scoring directly — no host-materialized
-        (size, 2K) grid and no device->host result pull.  Returns the
-        flat (size,) float32 device table for one rate vector."""
+        enumeration (``jnp.arange`` -> digits, states on the last axis)
+        feeds the Erlang-C + critical-path scoring directly — no
+        host-materialized (size, 2K) grid and no device->host result
+        pull.  Returns the flat (size,) float32 device table for one
+        rate vector, in row-major state order."""
         import jax
         import jax.numpy as jnp
 
@@ -257,9 +281,13 @@ class SizingSpace:
 
         def run(rates, use_kernel: bool):
             flat = jnp.arange(size, dtype=jnp.int32)
-            cand = jnp.stack([(flat // strides[d]) % shape[d]
-                              for d in range(len(shape))], axis=1)
-            y, _, _, _ = body(cand, rates, use_kernel)
+            # one scalar divisor per digit: with an array of divisors XLA
+            # folds the whole enumeration at compile time (minutes)
+            digits = [(flat // strides[d]) % shape[d]
+                      for d in range(len(shape))]
+            # sizes and replica counts interleave per tier -> (K, B) each
+            y, _, _, _ = body(jnp.stack(digits[0::2]),
+                              jnp.stack(digits[1::2]), rates, use_kernel)
             return y
 
         return jax.jit(run, static_argnames=("use_kernel",))

@@ -97,13 +97,16 @@ def wkv6(r, k, v, logw, u, chunk: int = 64):
     return out.transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.jit, static_argnames=("c_max", "sat_s", "block_b"))
-def sizing_latency(lam, mu, repl, visit_w, adj, c_max: int,
-                   sat_s: float = 1e4, block_b: int = 32):
-    """(B, K) tier rates/replicas + (K, K) adjacency -> (sojourn, path),
-    both (B, K) fp32 (container-sizing M/M/c + critical-path evaluator)."""
-    return _sl.sizing_latency(lam, mu, repl, visit_w, adj, c_max=c_max,
-                              sat_s=sat_s, block_b=block_b)
+@functools.partial(jax.jit, static_argnames=(
+    "visits", "edges", "entries", "c_max", "sat_s", "block_b"))
+def sizing_latency(lam, mu, repl, visits, edges, entries, c_max: int,
+                   sat_s: float = 1e4, block_b: int = 512):
+    """(K,) tier arrival rates + (K, B) per-state service rates/replicas,
+    static DAG (visits (C, K), edges, entries as tuples) -> (C, B) fp32
+    entry-tier critical paths (container-sizing M/M/c evaluator)."""
+    return _sl.sizing_latency(lam, mu, repl, visits=visits, edges=edges,
+                              entries=entries, c_max=c_max, sat_s=sat_s,
+                              block_b=block_b)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_m"))

@@ -161,3 +161,27 @@ def sizing_latency_ref(lam, mu, repl, visit_w, adj, *, c_max: int,
         masked = jnp.where(edges[None, :, :], latency[:, None, :], -1e30)
         latency = node + jnp.maximum(jnp.max(masked, axis=2), 0.0)
     return soj, latency
+
+
+def sizing_entry_latency_ref(lam, mu, repl, *, visits, edges, entries,
+                             c_max: int, sat_s: float = 1e4):
+    """:func:`sizing_latency_ref` on the inputs of
+    :func:`repro.kernels.sizing_latency.sizing_latency`: lam (K,),
+    mu/repl (K, B), static visits (C, K), (caller, callee) edges and
+    entry tiers -> (C, B) entry-tier critical paths, fp32.
+
+    Folds every class into its own row (row b * C + c) and relaxes the
+    dense adjacency ``K`` times — the kernel's sweep shares none of it.
+    """
+    K, B = mu.shape
+    w = jnp.asarray(visits, jnp.float32)                   # (C, K)
+    C = w.shape[0]
+    adj = jnp.zeros((K, K), bool)
+    for v, u in edges:
+        adj = adj.at[v, u].set(True)
+    _, path = sizing_latency_ref(
+        jnp.broadcast_to(lam, (B * C, K)), jnp.repeat(mu.T, C, axis=0),
+        jnp.repeat(repl.T, C, axis=0), jnp.tile(w, (B, 1)), adj,
+        c_max=c_max, sat_s=sat_s)
+    entry = jnp.asarray(entries, jnp.int32)
+    return path.reshape(B, C, K)[:, jnp.arange(C), entry].T

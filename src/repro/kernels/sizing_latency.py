@@ -1,15 +1,19 @@
 """Pallas TPU kernel: M/M/c tier sojourns + DAG critical-path latency.
 
 The container-sizing evaluator's hot spot is scoring B candidate sizings
-of a K-tier microservice DAG in one shot: for every (candidate, tier)
-cell, an Erlang-C M/M/c sojourn (queue wait + service) from the tier's
-arrival rate, per-replica service rate and replica count; then, per row,
-the visit-weighted *critical path* over the DAG — the heaviest
-entry-to-leaf path where each node costs ``visits x sojourn`` and
-parallel fan-out composes by max (sequential chains by sum).  Jackson's
-independence approximation makes the per-tier queues separable, so the
-whole thing is (B, K) elementwise work plus a depth-bounded masked-max
-relaxation — VPU-shaped, one VMEM pass per row block.
+(states) of a K-tier microservice DAG in one shot: for every (tier,
+state) cell, an Erlang-C M/M/c sojourn (queue wait + service) from the
+tier's arrival rate, per-replica service rate and replica count; then,
+per request class, the visit-weighted *critical path* from the class's
+entry tier — the heaviest entry-to-leaf path where each node costs
+``visits x sojourn`` and parallel fan-out composes by max (sequential
+chains by sum).  Jackson's independence approximation makes the
+per-tier queues separable, so the whole thing is elementwise VPU work.
+
+Layout: states on lanes, tiers on sublanes.  Inputs are (K, B) with B
+on the lane axis, so each tier's sojourn is computed once per state (not
+once per class) and no lane is padding; the per-tier arrival rates (K,)
+are the only traced per-call input besides them.
 
 Erlang C is computed through the Erlang-B blocking recurrence
 
@@ -21,12 +25,14 @@ fused multiply-divide per replica step up to the static ``c_max``.
 Unstable cells (lambda >= c mu) saturate to ``sat_s`` seconds, a finite
 cliff the annealing acceptance rule can walk off of.
 
-The critical path is a ``depth``-step relaxation of
+The critical path is one reverse-topological sweep over the DAG's static
+edge list, all classes at once (classes on sublanes):
 
-    L[v] = w[v] * T[v] + max(0, max_{(v,u) in E} L[u])
+    L[c, v] = w[c, v] * T[v] + max(0, max_{(v,u) in E} L[c, u]),
+    v = K-1 .. 0
 
-over the (K, K) adjacency matrix; ``depth = K`` makes it exact for any
-DAG on K topologically-ordered tiers.
+exact because the tiers are topologically ordered (every edge points to
+a later tier); class c's latency is L at its entry tier.
 """
 
 from __future__ import annotations
@@ -35,111 +41,114 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# Masked-out adjacency entries take this value inside the max-relaxation;
-# any real path latency dominates it, and rows with no children fall back
-# to 0 through the outer maximum.
-_NEG = -1e30
 
-
-def _sizing_kernel(lam_ref, mu_ref, repl_ref, w_ref, adj_ref,
-                   soj_ref, path_ref, *, c_max: int, depth: int,
-                   sat_s: float):
-    lam = lam_ref[...].astype(jnp.float32)        # (block_b, Kp)
-    mu = mu_ref[...].astype(jnp.float32)
+def _sizing_kernel(lam_ref, mu_ref, repl_ref, out_ref, *, c_max: int,
+                   sat_s: float, visits, children, entries):
+    lam = lam_ref[...].astype(jnp.float32)        # (K, 1)
+    mu = mu_ref[...].astype(jnp.float32)          # (K, block_b)
     c = repl_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
-    adj = adj_ref[...] != 0                        # (Kp, Kp)
 
     a = lam / mu                                   # offered load (Erlangs)
-
-    def erlang_step(k, carry):
-        b, b_at_c = carry
-        kf = k.astype(jnp.float32)
-        b = a * b / (kf + a * b)
-        b_at_c = jnp.where(kf == c, b, b_at_c)
-        return b, b_at_c
-
-    _, b_c = jax.lax.fori_loop(
-        1, c_max + 1, erlang_step,
-        (jnp.ones_like(a), jnp.zeros_like(a)))
+    b = jnp.ones_like(a)
+    b_c = jnp.zeros_like(a)
+    for k in range(1, c_max + 1):
+        b = a * b / (k + a * b)
+        b_c = jnp.where(c == k, b, b_c)
     rho = a / jnp.maximum(c, 1.0)
     p_wait = b_c / jnp.maximum(1.0 - rho * (1.0 - b_c), 1e-12)
     slack = c * mu - lam                           # spare service capacity
-    t = jnp.where(slack > 1e-9,
-                  p_wait / jnp.maximum(slack, 1e-12) + 1.0 / mu,
-                  sat_s)
-    soj_ref[...] = t
+    soj = jnp.where(slack > 1e-9,
+                    p_wait / jnp.maximum(slack, 1e-12) + 1.0 / mu,
+                    sat_s)
 
-    node = w * t                                   # visit-weighted cost
+    cls = jax.lax.broadcasted_iota(jnp.int32, (len(visits), 1), 0)
 
-    def relax(_, latency):
-        # child[b, v] = max_u adj[v, u] ? latency[b, u]
-        masked = jnp.where(adj[None, :, :], latency[:, None, :], _NEG)
-        child = jnp.max(masked, axis=2)
-        return node + jnp.maximum(child, 0.0)
+    def per_class(vals):
+        # static per-class scalars -> (C, 1) column, without a constant
+        # operand
+        col = jnp.full((len(vals), 1), vals[0], jnp.float32)
+        for ci, x in enumerate(vals[1:], 1):
+            col = jnp.where(cls == ci, x, col)
+        return col
 
-    path_ref[...] = jax.lax.fori_loop(0, depth, relax, node)
+    path = [None] * len(children)
+    for v in reversed(range(len(children))):
+        # visit-weighted node cost, (C, block_b)
+        node = per_class([w[v] for w in visits]) * soj[v:v + 1, :]
+        if children[v]:
+            child = path[children[v][0]]
+            for u in children[v][1:]:
+                child = jnp.maximum(child, path[u])
+            node = node + jnp.maximum(child, 0.0)
+        path[v] = node
+    out = path[entries[0]]
+    for ci, e in enumerate(entries):
+        if e != entries[0]:
+            out = jnp.where(cls == ci, path[e], out)
+    out_ref[...] = out
 
 
-def sizing_latency(lam, mu, repl, visit_w, adj, *, c_max: int,
-                   sat_s: float = 1e4, block_b: int = 32,
+def sizing_latency(lam, mu, repl, *, visits, edges, entries, c_max: int,
+                   sat_s: float = 1e4, block_b: int = 512,
                    interpret: bool | None = None):
-    """lam/mu/repl/visit_w (B, K) fp32, adj (K, K) bool -> (sojourn (B, K),
-    path (B, K)), both fp32.
+    """lam (K,), mu/repl (K, B) fp32 -> entry latency (C, B) fp32.
 
     ``lam`` is the tier arrival rate, ``mu`` the per-replica service rate
-    (must be > 0), ``repl`` the integer replica count as float (1 <= repl
-    <= c_max), ``visit_w`` the per-row node weights (a request class's
-    visit ratios), ``adj[v, u]`` True when tier v calls tier u (tiers
-    topologically ordered).  ``path[:, v]`` is the weighted critical path
-    of the sub-DAG rooted at v — end-to-end latency when v is the entry
-    tier.  Rows are padded to ``block_b`` multiples and K to the 128-lane
-    width; padding is load-free (lam 0, mu 1, repl 1, weights 0, no
-    edges) so it never influences real cells.
+    of each (tier, state) (must be > 0), ``repl`` the integer replica
+    count as float (1 <= repl <= c_max).  The DAG is static: ``visits``
+    the (C, K) per-class visit weights, ``edges`` (caller, callee) tier
+    index pairs with caller < callee (tiers topologically ordered),
+    ``entries`` each class's entry tier.  ``out[c, b]`` is class c's
+    weighted critical path from its entry tier in state b.  States are
+    padded to a ``block_b`` multiple with load-free cells (mu 1, repl 1),
+    sliced off on return.
     """
-    B, K = lam.shape
-    for name, x in (("mu", mu), ("repl", repl), ("visit_w", visit_w)):
-        if x.shape != (B, K):
-            raise ValueError(f"{name} shape {x.shape} != {(B, K)}")
-    if adj.shape != (K, K):
-        raise ValueError(f"adj shape {adj.shape} != {(K, K)}")
+    K, B = mu.shape
+    if repl.shape != (K, B):
+        raise ValueError(f"repl shape {repl.shape} != {(K, B)}")
+    if lam.shape != (K,):
+        raise ValueError(f"lam shape {lam.shape} != {(K,)}")
+    visits = tuple(tuple(float(x) for x in row) for row in visits)
+    entries = tuple(int(e) for e in entries)
+    C = len(visits)
+    if any(len(row) != K for row in visits) or len(entries) != C or not C:
+        raise ValueError(f"visits must be (C, {K}) with one entry a class")
+    if any(not 0 <= e < K for e in entries):
+        raise ValueError(f"entry tiers {entries} out of range({K})")
+    children: list[list[int]] = [[] for _ in range(K)]
+    for v, u in edges:
+        if not 0 <= v < u < K:
+            raise ValueError(f"edge ({v}, {u}) is not caller < callee < K")
+        children[v].append(int(u))
     if c_max < 1:
         raise ValueError("c_max must be >= 1")
+    if block_b < 128 or block_b % 128:
+        raise ValueError("block_b must be a positive multiple of 128")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
-    bb = min(block_b, max(B, 8))
-    Bp = -(-B // bb) * bb
-    Kp = -(-K // 128) * 128
+    Bp = -(-B // block_b) * block_b
 
-    def pad(x, fill):
-        out = jnp.full((Bp, Kp), fill, jnp.float32)
-        return out.at[:B, :K].set(x.astype(jnp.float32))
-
-    adj_p = jnp.zeros((Kp, Kp), jnp.int32).at[:K, :K].set(
-        adj.astype(jnp.int32))
+    def pad(x):
+        x = x.astype(jnp.float32)
+        if Bp == B:
+            return x
+        return jnp.pad(x, ((0, 0), (0, Bp - B)), constant_values=1.0)
 
     kernel = lambda *refs: _sizing_kernel(
-        *refs, c_max=int(c_max), depth=int(K), sat_s=float(sat_s))
-    soj, path = pl.pallas_call(
+        *refs, c_max=int(c_max), sat_s=float(sat_s), visits=visits,
+        children=tuple(map(tuple, children)), entries=entries)
+    out = pl.pallas_call(
         kernel,
-        grid=(Bp // bb,),
+        grid=(Bp // block_b,),
         in_specs=[
-            pl.BlockSpec((bb, Kp), lambda i: (i, 0)),   # lam
-            pl.BlockSpec((bb, Kp), lambda i: (i, 0)),   # mu
-            pl.BlockSpec((bb, Kp), lambda i: (i, 0)),   # repl
-            pl.BlockSpec((bb, Kp), lambda i: (i, 0)),   # visit_w
-            pl.BlockSpec((Kp, Kp), lambda i: (0, 0)),   # adj (shared)
+            pl.BlockSpec((K, 1), lambda i: (0, 0)),          # lam
+            pl.BlockSpec((K, block_b), lambda i: (0, i)),    # mu
+            pl.BlockSpec((K, block_b), lambda i: (0, i)),    # repl
         ],
-        out_specs=[
-            pl.BlockSpec((bb, Kp), lambda i: (i, 0)),
-            pl.BlockSpec((bb, Kp), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Bp, Kp), jnp.float32),
-            jax.ShapeDtypeStruct((Bp, Kp), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((C, block_b), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((C, Bp), jnp.float32),
         interpret=interpret,
-    )(pad(lam, 0.0), pad(mu, 1.0), pad(repl, 1.0), pad(visit_w, 0.0),
-      adj_p)
-    return soj[:B, :K], path[:B, :K]
+        name="sizing_latency",
+    )(lam.astype(jnp.float32).reshape(K, 1), pad(mu), pad(repl))
+    return out[:, :B]

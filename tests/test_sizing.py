@@ -4,6 +4,9 @@ sizing evaluator vs the numpy ground truth, the online SizingController
 (drift tracking, source seams), and container tenants inside the
 multi-tenant FleetController's capacity ledger."""
 
+import json
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -26,7 +29,8 @@ from repro.core import (
     full_grid,
     microservice_config_fn,
 )
-from repro.kernels.ref import sizing_latency_ref
+from repro.core.sizing import sizing_table_device
+from repro.kernels.ref import sizing_entry_latency_ref, sizing_latency_ref
 from repro.kernels.sizing_latency import sizing_latency
 from repro.workloads.microservice import (
     ContainerSize,
@@ -102,43 +106,89 @@ def test_mmc_sojourn_decreases_with_replicas_and_saturates():
 # The Pallas kernel vs the jnp reference (acceptance: 1e-5).
 # ---------------------------------------------------------------------------
 
+BOUTIQUE = json.loads((Path(__file__).resolve().parents[1] / "bench"
+                       / "configs" / "boutique-sizing-59k.json").read_text())
 
-@pytest.mark.parametrize("B,K,c_max", [
-    (1, 2, 1),       # tiny, heavily padded
-    (33, 6, 8),      # odd batch vs block size
-    (64, 10, 6),     # 10-tier DAG
+
+def _boutique_dag(replica_counts=None):
+    """Online Boutique's 10 services, 14 call edges and 6 Locust tasks."""
+    c = BOUTIQUE
+    dag = MicroserviceDAG(
+        tuple(ServiceTier(**t) for t in c["tiers"]),
+        tuple(tuple(e) for e in c["edges"]),
+        tuple(RequestClass(**k) for k in c["classes"]))
+    return SizingSpace(
+        dag, sizes=tuple(ContainerSize(**s) for s in c["sizes"]),
+        replica_counts=tuple(replica_counts or c["replica_counts"]),
+        price_per_core_hr=c["price_per_core_hr"],
+        lambda_cost=c["lambda_cost"], slo_penalty=c["slo_penalty"],
+        sat_s=c["sat_s"])
+
+
+def _static_dag(kind, rng):
+    """(visits, edges, entries) of the Boutique DAG, or of a random
+    topologically ordered one with fan-out from tier 0, two roots (tiers
+    0 and 1), an isolated last tier and a class entering at each."""
+    if kind == "boutique":
+        dag = _boutique_dag().dag
+        return (tuple(map(tuple, dag.visit_matrix().tolist())),
+                tuple((dag.index(u), dag.index(v)) for u, v in dag.edges),
+                tuple(int(e) for e in dag.entry_indices()))
+    K = 7
+    edges = [(0, 2), (0, 3), (0, 4)]
+    edges += [(v, u) for v in range(1, K - 1) for u in range(v + 1, K - 1)
+              if rng.random() < 0.4]
+    visits = rng.uniform(0.0, 2.0, (4, K)) * (rng.random((4, K)) < 0.8)
+    return (tuple(map(tuple, visits.tolist())), tuple(edges),
+            (0, 1, K - 1, 3))
+
+
+def _kernel_inputs(rng, K, B, c_max):
+    """Tier rates, and per-state service rates and replicas, with the
+    utilization bounded away from 1 (realistic deployments; the
+    near-critical regime is covered by the saturation test below)."""
+    lam = rng.uniform(5.0, 100.0, K).astype(np.float32)
+    repl = rng.integers(1, c_max + 1, (K, B)).astype(np.float32)
+    util = rng.uniform(0.05, 0.9, (K, B))
+    mu = (lam[:, None] / (util * repl)).astype(np.float32)
+    return tuple(map(jnp.asarray, (lam, mu, repl)))
+
+
+@pytest.mark.parametrize("dag,B,c_max", [
+    ("random", 1, 1),         # one state, heavily padded
+    ("random", 33, 8),        # odd batch vs the lane block
+    ("random", 700, 3),       # two blocks, the second partial
+    ("random", 1024, 1),      # exact block multiple
+    ("boutique", 1, 3),
+    ("boutique", 513, 8),     # one state over a block
+    ("boutique", 1500, 3),    # the benchmark's replica range
 ])
-def test_sizing_latency_kernel_matches_ref(B, K, c_max):
-    rng = np.random.default_rng(B + K)
-    mu = rng.uniform(5.0, 60.0, (B, K)).astype(np.float32)
-    repl = rng.integers(1, c_max + 1, (B, K)).astype(np.float32)
-    # utilization bounded away from 1 (realistic deployments); the
-    # near-critical regime is covered by the saturation test below
-    lam = (rng.uniform(0.05, 0.9, (B, K)) * mu * repl).astype(np.float32)
-    w = rng.uniform(0.0, 2.0, (B, K)).astype(np.float32)
-    adj = np.triu(rng.random((K, K)) < 0.4, 1)
-    args = tuple(map(jnp.asarray, (lam, mu, repl, w, adj)))
-    soj_k, path_k = sizing_latency(*args, c_max=c_max)
-    soj_r, path_r = sizing_latency_ref(*args, c_max=c_max)
-    np.testing.assert_allclose(np.asarray(soj_k), np.asarray(soj_r),
-                               rtol=1e-5, atol=1e-7)
-    np.testing.assert_allclose(np.asarray(path_k), np.asarray(path_r),
-                               rtol=1e-5, atol=1e-7)
+def test_sizing_latency_kernel_matches_ref(dag, B, c_max):
+    rng = np.random.default_rng(B + c_max)
+    visits, edges, entries = _static_dag(dag, rng)
+    kw = dict(visits=visits, edges=edges, entries=entries, c_max=c_max)
+    args = _kernel_inputs(rng, len(visits[0]), B, c_max)
+    got = np.asarray(sizing_latency(*args, **kw))
+    want = np.asarray(sizing_entry_latency_ref(*args, **kw))
+    assert got.shape == (len(visits), B)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
 
 
 def test_sizing_latency_kernel_saturation_agrees_with_ref():
+    """Every cell unstable: one class entering each tier of an edgeless
+    DAG reads each tier's sojourn, all at ``sat_s``."""
     rng = np.random.default_rng(3)
-    B, K = 16, 5
-    mu = rng.uniform(5.0, 40.0, (B, K)).astype(np.float32)
-    repl = rng.integers(1, 5, (B, K)).astype(np.float32)
-    lam = (mu * repl * 1.5).astype(np.float32)          # all unstable
-    w = np.ones((B, K), np.float32)
-    adj = np.zeros((K, K), bool)
-    args = tuple(map(jnp.asarray, (lam, mu, repl, w, adj)))
-    soj_k, _ = sizing_latency(*args, c_max=4, sat_s=777.0)
-    soj_r, _ = sizing_latency_ref(*args, c_max=4, sat_s=777.0)
-    assert (np.asarray(soj_k) == 777.0).all()
-    assert (np.asarray(soj_r) == 777.0).all()
+    K, B = 5, 16
+    mu = rng.uniform(5.0, 40.0, (K, B)).astype(np.float32)
+    repl = rng.integers(1, 5, (K, B)).astype(np.float32)
+    lam = (mu * repl).max(axis=1) * 1.5                  # all unstable
+    args = tuple(map(jnp.asarray, (lam.astype(np.float32), mu, repl)))
+    kw = dict(visits=tuple(map(tuple, np.eye(K).tolist())), edges=(),
+              entries=tuple(range(K)), c_max=4, sat_s=777.0)
+    soj_k = np.asarray(sizing_latency(*args, **kw))
+    soj_r = np.asarray(sizing_entry_latency_ref(*args, **kw))
+    assert (soj_k == 777.0).all()
+    assert (soj_r == 777.0).all()
 
 
 def test_sizing_latency_ops_wrapper_matches_ref():
@@ -147,19 +197,13 @@ def test_sizing_latency_ops_wrapper_matches_ref():
     from repro.kernels import ops
 
     rng = np.random.default_rng(7)
-    B, K = 12, 5
-    mu = rng.uniform(5.0, 40.0, (B, K)).astype(np.float32)
-    repl = rng.integers(1, 4, (B, K)).astype(np.float32)
-    lam = (rng.uniform(0.1, 0.8, (B, K)) * mu * repl).astype(np.float32)
-    w = rng.uniform(0.0, 2.0, (B, K)).astype(np.float32)
-    adj = np.triu(rng.random((K, K)) < 0.5, 1)
-    args = tuple(map(jnp.asarray, (lam, mu, repl, w, adj)))
-    soj_o, path_o = ops.sizing_latency(*args, c_max=4)
-    soj_r, path_r = sizing_latency_ref(*args, c_max=4)
-    np.testing.assert_allclose(np.asarray(soj_o), np.asarray(soj_r),
-                               rtol=1e-5, atol=1e-7)
-    np.testing.assert_allclose(np.asarray(path_o), np.asarray(path_r),
-                               rtol=1e-5, atol=1e-7)
+    visits, edges, entries = _static_dag("boutique", rng)
+    args = _kernel_inputs(rng, len(visits[0]), 300, 3)
+    kw = dict(visits=visits, edges=edges, entries=entries, c_max=3)
+    np.testing.assert_allclose(
+        np.asarray(ops.sizing_latency(*args, **kw)),
+        np.asarray(sizing_entry_latency_ref(*args, **kw)),
+        rtol=1e-5, atol=1e-7)
 
 
 def test_sizing_latency_critical_path_semantics():
@@ -174,8 +218,48 @@ def test_sizing_latency_critical_path_semantics():
     _, path = sizing_latency_ref(*map(jnp.asarray, (lam, mu, repl, w, adj)),
                                  c_max=1)
     # L[3] = 0.1, L[2] = 0.3, L[1] = 0.1 + max = 0.4, L[0] = 0.1 + 0.4
-    np.testing.assert_allclose(np.asarray(path)[0],
-                               [0.5, 0.4, 0.3, 0.1], rtol=1e-5)
+    want = [0.5, 0.4, 0.3, 0.1]
+    np.testing.assert_allclose(np.asarray(path)[0], want, rtol=1e-5)
+    # the kernel, one class entering at each tier
+    got = sizing_latency(jnp.asarray(lam[0]), jnp.asarray(mu.T),
+                         jnp.asarray(repl.T), visits=(tuple(w[0]),) * 4,
+                         edges=((0, 1), (1, 2), (1, 3)),
+                         entries=(0, 1, 2, 3), c_max=1)
+    np.testing.assert_allclose(np.asarray(got)[:, 0], want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("bad", [
+    dict(edges=((1, 0),)),                 # against the topological order
+    dict(edges=((0, 4),)),                 # unknown tier
+    dict(entries=(4,)),                    # unknown entry tier
+    dict(visits=((1.0, 1.0),)),            # visits not (C, K)
+])
+def test_sizing_latency_rejects_a_malformed_dag(bad):
+    kw = dict(visits=((1.0, 1.0, 1.0, 1.0),), edges=((0, 1),),
+              entries=(0,), c_max=2)
+    kw.update(bad)
+    ones = jnp.ones((4, 8), jnp.float32)
+    with pytest.raises(ValueError):
+        sizing_latency(jnp.zeros(4, jnp.float32), ones, ones, **kw)
+
+
+def test_sizing_table_kernel_matches_ref_program():
+    """The whole table program (enumeration, menus, kernel, objective) on
+    the Boutique DAG at 2 replica counts, 1,024 states: the kernel path
+    against the jnp reference path, the chip smoke run's check."""
+    spec = _boutique_dag(replica_counts=(1, 2))
+    assert spec.space.size() == 1024
+    # the night load: 10 tasks/s at Locust's task weights
+    weights = {"index": 1, "setCurrency": 2, "browseProduct": 10,
+               "addToCart": 2, "viewCart": 3, "checkout": 1}
+    mix = {k: 10.0 * w / sum(weights.values()) for k, w in weights.items()}
+    t_kernel = np.asarray(sizing_table_device(spec, mix, use_kernel=True),
+                          np.float64)
+    t_ref = np.asarray(sizing_table_device(spec, mix, use_kernel=False),
+                       np.float64)
+    assert t_kernel.shape == (1024,)
+    np.testing.assert_allclose(t_kernel, t_ref, rtol=1e-5)
+    assert int(np.argmin(t_kernel)) == int(np.argmin(t_ref))
 
 
 # ---------------------------------------------------------------------------

@@ -99,15 +99,16 @@ def test_pairwise_sqdist_compiles(one_chip):
 def test_sizing_latency_compiles_at_small_spec_width(one_chip):
     from benchmarks.container_sizing import small_spec
     spec = small_spec()
-    B, K = spec.space.size(), spec.dag.n_tiers
+    dag = spec.dag
+    B, K = spec.space.size(), dag.n_tiers
     assert (B, K) == (65_536, 8)
-    fn = jax.jit(lambda lam, mu, repl, w, adj: sizing_latency(
-        lam, mu, repl, w, adj, c_max=spec.c_max, interpret=False))
-    compiled = fn.lower(
-        _f32((B, K), one_chip), _f32((B, K), one_chip),
-        _f32((B, K), one_chip), _f32((B, K), one_chip),
-        jax.ShapeDtypeStruct((K, K), jnp.bool_, sharding=one_chip)
-    ).compile()
+    fn = jax.jit(lambda lam, mu, repl: sizing_latency(
+        lam, mu, repl, visits=tuple(map(tuple, dag.visit_matrix().tolist())),
+        edges=tuple((dag.index(u), dag.index(v)) for u, v in dag.edges),
+        entries=tuple(int(e) for e in dag.entry_indices()),
+        c_max=spec.c_max, interpret=False))
+    compiled = fn.lower(_f32((K,), one_chip), _f32((K, B), one_chip),
+                        _f32((K, B), one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
