@@ -256,21 +256,28 @@ def phase_surrogate(check: Checks, rounds: int = 6,
                        f"{sa.true_measures} real measures",
           y_best < y_first and sa.true_measures < 1000)
 
-    # same distribution as tests/test_kernels.py, at real widths
-    F = SpaceEncoding.from_space(space).feature_dim
+    # random states of the space, as tests/test_kernels.py draws them
+    enc = SpaceEncoding.from_space(space)
+    F = enc.feature_dim
     rng = np.random.default_rng(F)
-    xq = jnp.asarray(rng.normal(size=(q, F)), jnp.float32)
-    xm = jnp.asarray(rng.normal(size=(store_cap, F)), jnp.float32)
+    states = lambda n: np.stack([rng.integers(k, size=n)
+                                 for k in space.shape], axis=1)
+    probes, queries = states(store_cap), states(q)
     y = jnp.asarray(rng.normal(size=(store_cap,)), jnp.float32)
     w = jnp.asarray(rng.uniform(0.1, 1.0, size=(store_cap,)), jnp.float32)
+    live = jnp.ones((store_cap,), jnp.float32)
+    xq = jnp.asarray(enc.features(queries))
+    xm = jnp.asarray(enc.features(probes))
     m = sa.model
     for kind in ("idw", "rbf"):
         refit = _interp_jit(kind)
-        args = (xq, xm, y, w, m.length_scale, m.idw_power, m.eps)
-        text = refit.lower(*args).compile().as_text()
+        args = (jnp.asarray(probes, jnp.int32), y, w, live,
+                jnp.asarray(queries, jnp.int32), None)
+        kw = dict(qshape=None, **m._static())
+        text = refit.lower(*args, **kw).compile().as_text()
         check("surrogate", f"{kind} refit program compiles to "
                            f"tpu_custom_call", _has_kernel(text))
-        mean, dmin = refit(*args)
+        mean, dmin = refit(*args, **kw)
         want_mean, want_dmin = ref.fused_interp_ref(
             xq, xm, y, w, kind=kind, length_scale=m.length_scale,
             idw_power=m.idw_power, eps=m.eps)

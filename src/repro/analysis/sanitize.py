@@ -13,7 +13,8 @@ entry points
   per-mesh instances built by ``_fleet_shard_jit`` — both count under
   the ``anneal_fleet`` entry),
 * ``evaluate_sizing_batch`` (compiles through ``SizingSpace._eval_jit``),
-* the surrogate refit (``repro.core.surrogate._interp_jit``),
+* the surrogate refit (``repro.core.surrogate._interp_jit``, and the
+  device table programs built by ``_surrogate_table_jit``),
 
 counts **compilations** (via the jitted callable's tracing-cache size
 before/after each call) and **device->host transfers** (``np.asarray`` /
@@ -257,6 +258,14 @@ class Sanitizer:
             return _JitProbe("surrogate_refit", orig_interp(kind), self)
 
         self._patch(surrogate, "_interp_jit", interp)
+
+        orig_table = surrogate._surrogate_table_jit
+
+        @functools.cache
+        def table(*key):
+            return _JitProbe("surrogate_refit", orig_table(*key), self)
+
+        self._patch(surrogate, "_surrogate_table_jit", table)
 
         # device->host transfer counting: numpy's coercion entry points
         # plus jax.device_get, counted only for jax.Array operands

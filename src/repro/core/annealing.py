@@ -778,7 +778,10 @@ def anneal_fleet(
     None (uniform over the valid region) or (ndim,) / (C, ndim).
     ``per_chain_tables``: ``y_table`` carries a leading (C,) axis — one
     objective table per chain (multi-tenant fleets); combined with a
-    time axis the per-chain tables may also be dynamic.
+    time axis the per-chain tables may also be dynamic.  A static table
+    may also come flat, ``(size,)`` (``(C, size)`` per chain), in
+    row-major state order: on a TPU a table shaped like a space of many
+    short axes is laid out in padded tiles many times its size.
 
     ``extra_costs``: optional per-chain additive cost rows, shape
     ``(C,) + space.shape`` or ``(C, size)`` flattened — every measurement
@@ -799,7 +802,10 @@ def anneal_fleet(
     enc = _as_encoded(space)
     y = jnp.asarray(y_table, jnp.float32)
     base = y.ndim - (1 if per_chain_tables else 0)
-    if base == enc.ndim + 1:
+    flat = enc.ndim > 1 and base == 1 and y.shape[-1] == enc.size()
+    if flat:
+        dynamic = False
+    elif base == enc.ndim + 1:
         dynamic = True
     elif base == enc.ndim:
         dynamic = False
@@ -834,7 +840,7 @@ def anneal_fleet(
 
     lead = (n_chains,) if per_chain_tables else ()
     time = (n_steps,) if dynamic else ()
-    expect = lead + time + enc.shape
+    expect = lead + time + ((enc.size(),) if flat else enc.shape)
     if y.shape != expect:
         raise ValueError(f"table shape {y.shape} != expected {expect} "
                          f"(chains={n_chains}, steps={n_steps}, "

@@ -30,7 +30,9 @@ Pieces:
   feeds drift detection -> reheats.  Tables come from the batched
   evaluator by default; spaces beyond the 200k tabulation cap must
   inject a :class:`repro.core.surrogate.SurrogateSource` (probe and
-  interpolate), exactly like the other controllers.
+  interpolate), exactly like the other controllers.  On the device loop
+  its table is one device program: probes drawn from the round's key,
+  scored by the same Erlang-C path, every state interpolated.
 
 * Fleet integration — :class:`MicroserviceEvaluator` +
   :func:`microservice_config_fn` let microservice tenants join a
@@ -54,7 +56,7 @@ from .objective import Measurement
 from .procurement import ControllerMixin, Decision
 from .schedules import AdaptiveReheat
 from .state import ClusterConfig, ConfigSpace, Dimension
-from .surrogate import ObjectiveSource
+from .surrogate import ObjectiveSource, SurrogateSource
 from ..telemetry import provenance
 from ..telemetry import registry as metrics
 from ..telemetry import span
@@ -270,17 +272,31 @@ class SizingSpace:
         import jax
         import jax.numpy as jnp
 
+        size = int(np.prod(self.space.shape))
+        score = self._score_flat
+
+        def run(rates, use_kernel: bool):
+            return score(jnp.arange(size, dtype=jnp.int32), rates,
+                         use_kernel)
+
+        return jax.jit(run, static_argnames=("use_kernel",))
+
+    @functools.cached_property
+    def _score_flat(self):
+        """``score(flat (B,) int32, rates, use_kernel) -> y (B,)``: the
+        objective of the states with row-major flat indices ``flat``, by
+        :attr:`_eval_body` (traceable, un-jitted)."""
+        import jax.numpy as jnp
+
         body = self._eval_body
         shape = self.space.shape
-        size = int(np.prod(shape))
         strides, acc = [], 1
         for n in reversed(shape):
             strides.append(acc)
             acc *= n
         strides = tuple(reversed(strides))          # row-major
 
-        def run(rates, use_kernel: bool):
-            flat = jnp.arange(size, dtype=jnp.int32)
+        def score(flat, rates, use_kernel: bool):
             # one scalar divisor per digit: with an array of divisors XLA
             # folds the whole enumeration at compile time (minutes)
             digits = [(flat // strides[d]) % shape[d]
@@ -290,7 +306,16 @@ class SizingSpace:
                               jnp.stack(digits[1::2]), rates, use_kernel)
             return y
 
-        return jax.jit(run, static_argnames=("use_kernel",))
+        return score
+
+    @functools.cached_property
+    def _probe_scores(self) -> dict[bool, Callable]:
+        """``{use_kernel: score(flat, rates)}``: :attr:`_score_flat` with
+        the kernel choice bound, one stable callable each (the surrogate
+        table program is cached per score callable)."""
+        score = self._score_flat
+        return {uk: functools.partial(score, use_kernel=uk)
+                for uk in (False, True)}
 
 
 def sizing_table_device(
@@ -466,8 +491,11 @@ class SizingController(ControllerMixin):
     ``true_measures`` — the batched analog of ``ExhaustiveSource``) and
     refuses spaces beyond the 200k cap; inject a
     :class:`repro.core.surrogate.SurrogateSource` to probe-and-
-    interpolate large DAGs, or an ``ExhaustiveSource`` to force the
-    scalar one-state-at-a-time path.
+    interpolate large DAGs (on the device loop: ``n_probe`` states drawn
+    from each round's key and scored on the device, see
+    :meth:`SurrogateSource.device_table`; on the host path: through
+    ``host_objective``), or an ``ExhaustiveSource`` to force the scalar
+    one-state-at-a-time path.
     """
 
     def __init__(
@@ -544,8 +572,9 @@ class SizingController(ControllerMixin):
 
     #: Tables kept for the most recent distinct mixes.  A ramped/continuous
     #: mix schedule yields a fresh key every round; without eviction each
-    #: one pins a full-space float64 table (13 MB at the 1.68M-state rich
-    #: menu) forever, and old mixes never recur exactly.
+    #: one pins a full-space table forever (a float32 device table is 4 MB
+    #: at 1,048,576 states, so the cache holds 32 MB there), and old mixes
+    #: never recur exactly.
     TABLE_CACHE = 8
 
     def _table_for(self, rates: Mapping[str, float]) -> np.ndarray:
@@ -574,21 +603,35 @@ class SizingController(ControllerMixin):
                 self._tables.pop(next(iter(self._tables)))
         return self._tables[key]
 
-    def _dtable_for(self, rates: Mapping[str, float]):
+    def _dtable_for(self, rates: Mapping[str, float], round_key):
         """Device flat (size,) objective table for one mix — the fused
         enumeration+scoring jit when tables come from the batched
-        evaluator, a one-way host->device upload when an injected
-        ``objective_source`` builds them; same LRU policy as
-        :meth:`_table_for`."""
+        evaluator; with a :class:`SurrogateSource`, its device program
+        (probes drawn from ``round_key``, scored by the same Erlang-C
+        path and interpolated, nothing crossing to the host); a one-way
+        host->device upload when another ``objective_source`` builds
+        them.  Same LRU policy as :meth:`_table_for`."""
+        import jax
         import jax.numpy as jnp
 
         key = self._mix_key(rates)
         if key in self._dtables:
             self._dtables[key] = self._dtables.pop(key)
         else:
-            if self.objective_source is None:
+            src = self.objective_source
+            if src is None:
                 self._dtables[key] = sizing_table_device(self.spec, rates)
                 self._count_measures(self.space.size())
+            elif isinstance(src, SurrogateSource):
+                score = self.spec._probe_scores[
+                    jax.default_backend() == "tpu"]
+                self._dtables[key] = src.device_table(
+                    self._enc, score, round_key, jnp.asarray(
+                        self.spec.dag.rates_array(rates), jnp.float32))
+                self._count_measures(src.n_probe)
+                if metrics.get() is not None:
+                    metrics.inc("sizing/probes", src.n_probe)
+                    metrics.inc("sizing/interp_states", self.space.size())
             else:
                 self._dtables[key] = jnp.asarray(
                     self._table_for(rates), jnp.float32)
@@ -643,15 +686,14 @@ class SizingController(ControllerMixin):
                 key_r = jax.random.fold_in(self._key, r)
                 k_init, k_run = jax.random.split(key_r)
                 with span("sizing.refit", cat="sizing"):
-                    table_d = self._dtable_for(rates)
+                    table_d = self._dtable_for(rates, key_r)
                 inits_d = random_valid_states(
                     k_init, self._enc, self.n_chains).astype(jnp.int32)
                 inits_d = inits_d.at[0].set(
                     jnp.asarray(self.incumbent, jnp.int32))
                 with span("sizing.anneal", cat="sizing"):
                     out = anneal_fleet(
-                        k_run, self._enc, table_d.reshape(self._shape),
-                        self.steps_per_round,
+                        k_run, self._enc, table_d, self.steps_per_round,
                         jnp.broadcast_to(
                             jnp.asarray(taus, jnp.float32),
                             (self.n_chains, self.steps_per_round)),

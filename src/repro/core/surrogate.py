@@ -24,11 +24,12 @@ Pieces:
   encoding: ordinal axes become [0, 1]-scaled coordinates, categorical
   axes one-hot / sqrt(2), so ONE Euclidean squared-distance matrix
   carries both metrics (a categorical mismatch costs exactly as much as
-  traversing a full ordinal axis).  The (Q, M) distance matrix is a
-  Pallas kernel (:mod:`repro.kernels.surrogate_distance`) with a jnp
-  reference; :meth:`SurrogateModel.predict` returns estimates AND an
-  uncertainty channel (distance to the nearest measurement, scaled to
-  objective units).
+  traversing a full ordinal axis).  The interpolation is one fused
+  Pallas kernel (:func:`repro.kernels.surrogate_distance.fused_interp`,
+  query states on lanes, no (Q, M) matrix in HBM) with a jnp reference;
+  :meth:`SurrogateModel.predict` returns estimates AND an uncertainty
+  channel (distance to the nearest measurement, scaled to objective
+  units).
 
 * :class:`ObjectiveSource` — the injectable "where do objective tables
   come from" seam for the controllers: :class:`ExhaustiveSource` wraps
@@ -36,7 +37,10 @@ Pieces:
   valid state), :class:`SurrogateSource` probes a sparse sample and
   interpolates the rest — which frees the fleet path to drive
   :class:`repro.core.costmodel.MeasuredEvaluator` workloads, where every
-  avoided evaluation is real cluster time.
+  avoided evaluation is real cluster time.  Where the objective is itself
+  a device program (the sizing controller's Erlang-C scoring),
+  :meth:`SurrogateSource.device_table` draws, scores and interpolates in
+  one jitted program and the table never leaves the device.
 
 * :class:`SurrogateAnnealer` — the measure-refit-anneal loop.  Each round
   anneals a fleet of compiled chains on the surrogate restricted to a
@@ -216,12 +220,6 @@ class MeasurementStore:
 # ---------------------------------------------------------------------------
 
 
-#: Feature-space coordinate of measurement-padding rows: far beyond any
-#: real feature (which live in [0, 1] per axis), so padded entries can
-#: never be the nearest measurement and their kernel weight underflows
-#: to zero even before the zero recency weight kills them exactly.
-_PAD_FAR = 1.0e3
-
 #: Smallest padded axis length — below this, bucketing buys nothing.
 _PAD_MIN = 64
 
@@ -241,16 +239,23 @@ def _interp_jit(kind: str):
 
     from ..kernels.surrogate_distance import fused_interp
 
-    @functools.partial(jax.jit,
-                       static_argnames=("length_scale", "idw_power", "eps"))
-    def run(xq, xm, y, w_rec, length_scale, idw_power, eps):
+    @functools.partial(jax.jit, static_argnames=(
+        "shape", "categorical", "qshape", "length_scale", "idw_power",
+        "eps", "with_dmin"))
+    def run(probes, y, w_rec, valid, queries, offsets, *, shape,
+            categorical, qshape, length_scale, idw_power, eps,
+            with_dmin=True):
         # distance + recency-weighted reduction fused in ONE Pallas pass
-        # (no (Q, M) matrix in HBM); the hyper-parameters are static —
-        # they are model constants, and static scalars let the kernel
-        # bake them into the trace
-        return fused_interp(xq, xm, y, w_rec, kind=kind,
-                            length_scale=length_scale,
-                            idw_power=idw_power, eps=eps)
+        # (no (Q, M) matrix in HBM); queries are explicit states, or every
+        # state of ``qshape`` at ``offsets``, enumerated in the kernel.
+        # The hyper-parameters are static — they are model constants, and
+        # static scalars let the kernel bake them into the trace
+        return fused_interp(probes, y, w_rec, shape=shape,
+                            categorical=categorical, queries=queries,
+                            qshape=qshape, offsets=offsets, valid=valid,
+                            kind=kind, length_scale=length_scale,
+                            idw_power=idw_power, eps=eps,
+                            with_dmin=with_dmin)
 
     return run
 
@@ -298,9 +303,8 @@ def _dstore_insert_jit(capacity: int):
     import jax
     import jax.numpy as jnp
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4, 5))
-    def insert(states, feats, ys, ts, seq, wmask, state, feat, y, t,
-               next_seq):
+    @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
+    def insert(states, ys, ts, seq, wmask, state, y, t, next_seq):
         cap = seq.shape[0]
         usable = jnp.arange(cap, dtype=jnp.int32) < capacity
         valid = seq >= 0
@@ -317,8 +321,8 @@ def _dstore_insert_jit(capacity: int):
             jnp.where(valid, seq, imax)).astype(jnp.int32)
         slot = jnp.where(match.any(), slot_match,
                          jnp.where(empty.any(), slot_empty, slot_evict))
-        return (states.at[slot].set(state), feats.at[slot].set(feat),
-                ys.at[slot].set(y), ts.at[slot].set(t),
+        return (states.at[slot].set(state), ys.at[slot].set(y),
+                ts.at[slot].set(t),
                 seq.at[slot].set(next_seq), wmask.at[slot].set(1.0))
 
     return insert
@@ -380,8 +384,7 @@ class DeviceMeasurementStore:
     """Device-resident twin of :class:`MeasurementStore`.
 
     Fixed-capacity, pow-2-bucketed device arrays — states (cap, ndim)
-    int32, features (cap, F) f32 (padding rows at ``_PAD_FAR``),
-    objectives / timestamps (cap,) f32, a refresh-order sequence number
+    int32, objectives / timestamps (cap,) f32, a refresh-order sequence number
     (cap,) int32 (-1 = empty) and a validity weight mask (cap,) f32 —
     updated by a jitted, buffer-donating insert with latest-wins dedup
     and stalest-first eviction, so the numpy store's ``best()`` /
@@ -391,8 +394,8 @@ class DeviceMeasurementStore:
     Valid rows always form a compact prefix (inserts take the lowest
     free slot; eviction reuses the evicted slot), so
     :meth:`refit_view`'s pow-2-bucket slices carry every live entry plus
-    exactly-zero-contribution padding — the same padding contract as
-    :meth:`SurrogateModel.predict`.
+    padding rows of zero weight, marked dead — the same padding contract
+    as :meth:`SurrogateModel.predict`.
 
     A host-side key shadow (dict in refresh order, no device reads)
     mirrors membership and count; ``load`` bulk-rebuilds from a numpy
@@ -412,9 +415,7 @@ class DeviceMeasurementStore:
         self.half_life = half_life
         self.capacity = int(capacity)
         self.cap = _bucket(self.capacity)
-        F = encoding.feature_dim
         self._states = jnp.zeros((self.cap, self.ndim), jnp.int32)
-        self._feats = jnp.full((self.cap, F), _PAD_FAR, jnp.float32)
         self._ys = jnp.zeros((self.cap,), jnp.float32)
         self._ts = jnp.zeros((self.cap,), jnp.float32)
         self._seq = jnp.full((self.cap,), -1, jnp.int32)
@@ -434,12 +435,11 @@ class DeviceMeasurementStore:
         key = tuple(int(i) for i in state)
         if len(key) != self.ndim:
             raise ValueError(f"state rank {len(key)} != ndim {self.ndim}")
-        feat = self.encoding.features([key])[0]
-        (self._states, self._feats, self._ys, self._ts, self._seq,
+        (self._states, self._ys, self._ts, self._seq,
          self._wmask) = _dstore_insert_jit(self.capacity)(
-            self._states, self._feats, self._ys, self._ts, self._seq,
-            self._wmask, jnp.asarray(key, jnp.int32), jnp.asarray(feat),
-            jnp.float32(y), jnp.float32(t), jnp.int32(self._next_seq))
+            self._states, self._ys, self._ts, self._seq, self._wmask,
+            jnp.asarray(key, jnp.int32), jnp.float32(y), jnp.float32(t),
+            jnp.int32(self._next_seq))
         self._next_seq += 1
         # host key shadow: delete-then-insert + pop-front, the numpy
         # store's exact refresh-order semantics
@@ -456,18 +456,14 @@ class DeviceMeasurementStore:
 
         obs, ys, ts = store.arrays()
         n = len(obs)
-        F = self.encoding.feature_dim
         self._states = jnp.zeros((self.cap, self.ndim), jnp.int32)
-        self._feats = jnp.full((self.cap, F), _PAD_FAR, jnp.float32)
         self._ys = jnp.zeros((self.cap,), jnp.float32)
         self._ts = jnp.zeros((self.cap,), jnp.float32)
         self._seq = jnp.full((self.cap,), -1, jnp.int32)
         self._wmask = jnp.zeros((self.cap,), jnp.float32)
         if n:
-            feats = self.encoding.features(obs)
             self._states = self._states.at[:n].set(
                 jnp.asarray(obs, jnp.int32))
-            self._feats = self._feats.at[:n].set(jnp.asarray(feats))
             self._ys = self._ys.at[:n].set(jnp.asarray(ys, jnp.float32))
             self._ts = self._ts.at[:n].set(jnp.asarray(ts, jnp.float32))
             self._seq = self._seq.at[:n].set(
@@ -487,16 +483,17 @@ class DeviceMeasurementStore:
             self._wmask, self._ts, jnp.float32(now))
 
     def refit_view(self, now: float, m_bucket: int | None = None):
-        """Device (feats, ys, recency) slices for the fused refit:
+        """Device (states, ys, recency, live) slices for the fused refit:
         ``m_bucket`` rows (default: the pow-2 bucket of the live count)
-        — every live entry plus padding rows whose far features and zero
-        weights contribute exactly nothing."""
+        — every live entry plus padding rows whose zero weight
+        contributes exactly nothing and whose zero ``live`` keeps them
+        from ever being the nearest measurement."""
         if m_bucket is None:
             m_bucket = _bucket(len(self._keys))
         m_bucket = min(m_bucket, self.cap)
         rec = self.weights_device(now)
-        return (self._feats[:m_bucket], self._ys[:m_bucket],
-                rec[:m_bucket])
+        return (self._states[:m_bucket], self._ys[:m_bucket],
+                rec[:m_bucket], self._wmask[:m_bucket])
 
     def y_scale_device(self):
         """Device objective scale: spread of live objectives, or
@@ -642,14 +639,13 @@ class SurrogateModel:
         if self.kind not in ("idw", "rbf"):
             raise ValueError(f"unknown surrogate kind {self.kind!r}")
 
-    def predict(
-        self,
-        states: np.ndarray | Sequence[Sequence[int]],
-        store: MeasurementStore,
-        now: float | None = None,
-    ) -> tuple[np.ndarray, np.ndarray]:
-        """(Q, ndim) query index vectors -> (estimates (Q,), uncertainty
-        (Q,)), both float64.  Requires at least one measurement."""
+    def _refit_args(self, store: MeasurementStore, now: float | None):
+        """The store's device refit inputs and its objective scale.
+
+        The measurement axis is padded to a power-of-two bucket so the
+        online store's growth doesn't retrace the jitted interpolator
+        every round: padded rows carry zero recency weight (exactly zero
+        kernel contribution) and are marked dead (never the nearest)."""
         if len(store) == 0:
             raise ValueError("cannot predict from an empty MeasurementStore")
         import jax.numpy as jnp
@@ -658,45 +654,60 @@ class SurrogateModel:
         rec = store.weights(float(ts.max()) if now is None else float(now))
         spread = float(ys.max() - ys.min())
         y_scale = spread if spread > 0 else max(1.0, abs(float(ys.mean())))
+        pad = _bucket(len(obs)) - len(obs)
+        live = np.concatenate([np.ones(len(obs)), np.zeros(pad)])
+        obs = np.concatenate([obs, np.zeros((pad, obs.shape[1]), np.int32)])
+        ys = np.concatenate([ys, np.zeros(pad)])
+        rec = np.concatenate([rec, np.zeros(pad)])
+        return ((jnp.asarray(obs, jnp.int32), jnp.asarray(ys, jnp.float32),
+                 jnp.asarray(rec, jnp.float32),
+                 jnp.asarray(live, jnp.float32)), y_scale)
 
-        # pad the measurement axis to a power-of-two bucket so the online
-        # store's growth doesn't retrace the jitted interpolator every
-        # round: padded rows sit at _PAD_FAR (never nearest) with zero
-        # recency weight (exactly zero kernel contribution), so the
-        # result is bit-identical to the unpadded call
-        feats_m = self.encoding.features(obs)
-        m_cap = _bucket(len(obs))
-        if m_cap != len(obs):
-            pad = m_cap - len(obs)
-            feats_m = np.concatenate(
-                [feats_m,
-                 np.full((pad, feats_m.shape[1]), _PAD_FAR, np.float32)])
-            ys = np.concatenate([ys, np.zeros(pad)])
-            rec = np.concatenate([rec, np.zeros(pad)])
-        xm = jnp.asarray(feats_m)
-        y_d = jnp.asarray(ys, jnp.float32)
-        rec_d = jnp.asarray(rec, jnp.float32)
+    def _static(self) -> dict[str, Any]:
+        enc = self.encoding
+        return {"shape": enc.shape, "categorical": enc.categorical,
+                "length_scale": self.length_scale,
+                "idw_power": self.idw_power, "eps": self.eps}
+
+    def predict(
+        self,
+        states: np.ndarray | Sequence[Sequence[int]],
+        store: MeasurementStore,
+        now: float | None = None,
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """(Q, ndim) query index vectors -> (estimates (Q,), uncertainty
+        (Q,)), both float64.  Requires at least one measurement."""
+        import jax.numpy as jnp
+
+        args, y_scale = self._refit_args(store, now)
         run = _interp_jit(self.kind)
-
         states = np.asarray(states, np.int64).reshape(-1, self.encoding.ndim)
         means, dmins = [], []
         for lo in range(0, len(states), self.chunk):
-            feats_q = self.encoding.features(states[lo:lo + self.chunk])
-            n_q = len(feats_q)
-            # queries bucket too: the moving window clips at space edges,
-            # and a fresh Q shape is just as much a retrace as a fresh M
+            q = states[lo:lo + self.chunk]
+            n_q = len(q)
+            # queries bucket too: a fresh Q shape is just as much a
+            # retrace as a fresh M
             q_cap = min(_bucket(n_q), self.chunk)
-            if q_cap != n_q:
-                feats_q = np.concatenate(
-                    [feats_q,
-                     np.zeros((q_cap - n_q, feats_q.shape[1]), np.float32)])
-            m, d = run(jnp.asarray(feats_q), xm, y_d, rec_d,
-                       self.length_scale, self.idw_power, self.eps)
+            q = np.concatenate([q, np.zeros((q_cap - n_q, q.shape[1]),
+                                            np.int64)])
+            m, d = run(*args, jnp.asarray(q, jnp.int32), None, qshape=None,
+                       **self._static())
             means.append(np.asarray(m, np.float64)[:n_q])
             dmins.append(np.asarray(d, np.float64)[:n_q])
         mean = np.concatenate(means)
         unc = y_scale * np.concatenate(dmins)
         return mean, unc
+
+    def predict_all(self, store: MeasurementStore,
+                    now: float | None = None) -> np.ndarray:
+        """Estimates (float64) of every state of the encoding's space, in
+        row-major order: the kernel enumerates the states itself, so no
+        grid of them is built anywhere."""
+        args, _ = self._refit_args(store, now)
+        mean = _interp_jit(self.kind)(*args, None, None, qshape=None,
+                                      with_dmin=False, **self._static())
+        return np.asarray(mean, np.float64)
 
 
 # ---------------------------------------------------------------------------
@@ -733,6 +744,66 @@ class ObjectiveSource:
         raise NotImplementedError
 
 
+#: ``fold_in`` data that derives a round's probe key from its round key,
+#: so the probe draw is independent of the chains' (start, run) split
+PROBE_STREAM = 0x70726F62
+
+
+def draw_probes(key, size: int, n: int):
+    """``n`` distinct flat states of ``[0, size)`` from ``key``, on the
+    device: ``2 n`` uniform draws, of which the first ``n`` distinct in
+    draw order are kept.  Returns (flat (n,) int32, weight (n,) float32):
+    weight 1 on a drawn state, 0 on a slot left over when the draws hold
+    fewer than ``n`` distinct states (there the slot repeats state 0 and
+    contributes nothing).  A plain reference can repeat the draw with
+    ``jax.random.randint`` and a first-occurrence dedup."""
+    import jax
+    import jax.numpy as jnp
+
+    cand = jax.random.randint(key, (2 * n,), 0, size, dtype=jnp.int32)
+    order = jnp.argsort(cand, stable=True)
+    s = cand[order]
+    first_sorted = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]])
+    first = jnp.zeros((2 * n,), bool).at[order].set(first_sorted)
+    rank = jnp.cumsum(first) - 1                   # among distinct draws
+    slot = jnp.where(first & (rank < n), rank, n)
+    flat = jnp.zeros((n,), jnp.int32).at[slot].set(cand, mode="drop")
+    weight = jnp.zeros((n,), jnp.float32).at[slot].set(1.0, mode="drop")
+    return flat, weight
+
+
+@functools.cache
+def _surrogate_table_jit(shape: tuple, categorical: tuple, n_probe: int,
+                         kind: str, length_scale: float, idw_power: float,
+                         eps: float, score: Callable):
+    """The jitted device table program of :meth:`SurrogateSource.
+    device_table`, one per (space, source settings, score)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..kernels.surrogate_distance import fused_interp
+
+    size = math.prod(shape)
+    strides, acc = [], 1
+    for n in reversed(shape):
+        strides.append(acc)
+        acc *= n
+    strides = tuple(reversed(strides))              # row-major
+
+    def surrogate_table(key, *args):
+        flat, weight = draw_probes(jax.random.fold_in(key, PROBE_STREAM),
+                                   size, n_probe)
+        y = score(flat, *args)
+        probes = jnp.stack([(flat // st) % n
+                            for st, n in zip(strides, shape)], axis=1)
+        return fused_interp(probes, y, weight, shape=shape,
+                            categorical=categorical, kind=kind,
+                            length_scale=length_scale,
+                            idw_power=idw_power, eps=eps, with_dmin=False)
+
+    return jax.jit(surrogate_table)
+
+
 class ExhaustiveSource(ObjectiveSource):
     """The historical behavior: one real evaluation per valid state."""
 
@@ -761,6 +832,12 @@ class SurrogateSource(ObjectiveSource):
     simulator sweep and a day of cluster time under a
     :class:`repro.core.costmodel.MeasuredEvaluator`.
 
+    :meth:`table` probes through a host ``fn`` and returns a host array.
+    :meth:`device_table` is the device path for an objective that is
+    itself a device program: probes drawn from a JAX key, scored and
+    interpolated in ONE jitted program whose table never leaves the
+    device.
+
     With ``recycle_store`` set (typically the same store a
     :class:`repro.core.evalpipe.SpeculativePipeline` recycles
     mis-speculated measurements into), every in-bounds entry warm-starts
@@ -788,6 +865,38 @@ class SurrogateSource(ObjectiveSource):
         self.recycle_store = recycle_store
         self.recycled_used = 0
         self._rng = np.random.default_rng(seed)
+
+    def device_table(self, space: ConfigSpace | EncodedSpace,
+                     score: Callable, key, *args):
+        """(size,) float32 device table of one round, built by ONE jitted
+        program (``jit_surrogate_table``): ``n_probe`` distinct states
+        drawn from ``key`` (:func:`draw_probes`), scored on the device by
+        ``score(flat (n,) int32, *args) -> (n,)``, and the model's
+        interpolation of every state from them, in row-major order.
+        Nothing is read back and nothing is uploaded but ``args``.
+
+        ``score`` must be a stable callable (the program is cached per
+        source settings and ``score``); every state of the space must be
+        valid, and the recycle store, whose measurements live on the
+        host, warm-starts :meth:`table` only."""
+        if isinstance(space, ConfigSpace):
+            space = space.encoded(max_size=max(space.size(), 1))
+        if space.valid_mask is not None:
+            raise ValueError("device_table needs a space whose every state "
+                             "is valid")
+        if self.recycle_store is not None:
+            raise ValueError("recycled host measurements warm-start the "
+                             "host table path only (device_loop=False)")
+        size = math.prod(space.shape)
+        if size > self.max_size:
+            raise ValueError(f"space too large to materialize: {size}")
+        model = self.model or SurrogateModel(SpaceEncoding.from_space(space))
+        run = _surrogate_table_jit(
+            space.shape, space.categorical, self.n_probe, model.kind,
+            model.length_scale, model.idw_power, model.eps, score)
+        self.true_measures += self.n_probe
+        self.surrogate_queries += size
+        return run(key, *args)
 
     def _probe_states(self, space: ConfigSpace,
                       valid_mask: np.ndarray | None) -> np.ndarray:
@@ -852,10 +961,8 @@ class SurrogateSource(ObjectiveSource):
             store.add(s, float(fn(space.decode([int(i) for i in s]))), 0.0)
             self.true_measures += 1
         model = self.model or SurrogateModel(SpaceEncoding.from_space(space))
-        grid = np.indices(space.shape).reshape(len(space.shape), -1).T
-        mean, _ = model.predict(grid, store)
-        self.surrogate_queries += len(grid)
-        Y = mean.reshape(space.shape)
+        Y = model.predict_all(store).reshape(space.shape)
+        self.surrogate_queries += space.size()
         if valid_mask is not None:
             Y = np.where(np.asarray(valid_mask), Y, np.inf)
         return Y
@@ -1037,7 +1144,7 @@ class SurrogateAnnealer:
         self.device_loop = bool(device_loop)
         self._dstore: DeviceMeasurementStore | None = None
         self._dstore_version = -1
-        self._feat_cache: dict[tuple[int, ...], Any] = {}
+        self._offs_cache: dict[tuple[int, ...], Any] = {}
         if init is None:
             init = self._random_valid_state()
         if not space.contains(init):
@@ -1109,25 +1216,18 @@ class SurrogateAnnealer:
             self._dstore.load(self.store)
             self._dstore_version = self.store._version
 
-    def _window_feats(self, sub: ConfigSpace, offs: np.ndarray):
-        """Device query features for every window state, padded to the
-        pow-2 query bucket — cached per window position (the host
-        encoding runs once per position the incumbent ever centers)."""
+    def _window_offsets(self, offs: np.ndarray):
+        """Device copy of a window's per-axis offsets, cached per window
+        position (uploaded once per position the incumbent ever
+        centers)."""
         key = tuple(int(o) for o in offs)
-        feats = self._feat_cache.get(key)
-        if feats is None:
+        off_d = self._offs_cache.get(key)
+        if off_d is None:
             import jax.numpy as jnp
 
-            grid = np.indices(sub.shape).reshape(len(sub.shape), -1).T
-            fq = self.model.encoding.features(grid + offs)
-            W = len(fq)
-            q_cap = _bucket(W)
-            if q_cap != W:
-                fq = np.concatenate(
-                    [fq, np.zeros((q_cap - W, fq.shape[1]), np.float32)])
-            feats = jnp.asarray(fq)
-            self._feat_cache[key] = feats
-        return feats
+            off_d = jnp.asarray(key, jnp.int32)
+            self._offs_cache[key] = off_d
+        return off_d
 
     def _window_enc(self, sub: ConfigSpace, offs: np.ndarray):
         key = tuple(int(o) for o in offs)
@@ -1196,15 +1296,15 @@ class SurrogateAnnealer:
             # single bulk host round-trip; only the final (m, ndim)
             # decision packet is read back
             self._sync_device_store()
-            xq = self._window_feats(sub, offs)
             mb = min(_bucket(len(self.store)), self._dstore.cap)
-            xm, ys_d, rec_d = self._dstore.refit_view(t, mb)
+            probes, ys_d, rec_d, live_d = self._dstore.refit_view(t, mb)
             with span("surrogate.refit", cat="surrogate"):
-                mean_q, dmin_q = _interp_jit(self.model.kind)(
-                    xq, xm, ys_d, rec_d, self.model.length_scale,
-                    self.model.idw_power, self.model.eps)
-            unc_q = self._dstore.y_scale_device() * dmin_q
-            mean_w, unc_w = mean_q[:W], unc_q[:W]
+                # every window state, enumerated inside the kernel
+                mean_w, dmin_w = _interp_jit(self.model.kind)(
+                    probes, ys_d, rec_d, live_d, None,
+                    self._window_offsets(offs), qshape=sub.shape,
+                    **self.model._static())
+            unc_w = self._dstore.y_scale_device() * dmin_w
             self.surrogate_queries += W
 
             # chain 0 starts at the incumbent (always inside its own

@@ -181,6 +181,22 @@ def test_fleet_per_chain_tables_are_independent_tenants():
     assert np.bincount(tails[1]).argmax() == 6
 
 
+@pytest.mark.parametrize("per_chain", [False, True])
+def test_fleet_takes_a_static_table_flat(per_chain):
+    """A flat (size,) table, or (C, size) per chain, in row-major state
+    order walks exactly as the same table shaped like the space."""
+    space = _mixed_space()
+    table = np.nan_to_num(_mixed_table(space), nan=1e6, posinf=1e6)
+    shaped = np.stack([table, table[::-1]]) if per_chain else table
+    flat = shaped.reshape((2, -1) if per_chain else (-1,))
+    runs = [anneal_fleet(jax.random.key(8), space, t, 40, taus=0.5,
+                         n_chains=2, per_chain_tables=per_chain)
+            for t in (shaped, flat)]
+    for k in ("states", "ys", "accepts", "inits"):
+        np.testing.assert_array_equal(np.asarray(runs[0][k]),
+                                      np.asarray(runs[1][k]))
+
+
 def test_fleet_rejects_mismatched_table_shape():
     """A dynamic table whose time axis disagrees with n_steps must raise,
     not silently reshape into interleaved garbage."""

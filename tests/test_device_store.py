@@ -151,9 +151,9 @@ def test_donation_safety_held_views_survive_inserts():
         s = (int(rng.integers(6)), int(rng.integers(3)))
         host.add(s, float(i), float(i))
         dev.add(s, float(i), float(i))
-    feats0, ys0, rec0 = dev.refit_view(now=8.0)
-    before = (np.asarray(feats0).copy(), np.asarray(ys0).copy(),
-              np.asarray(rec0).copy())
+    states0, ys0, rec0, live0 = dev.refit_view(now=8.0)
+    before = (np.asarray(states0).copy(), np.asarray(ys0).copy(),
+              np.asarray(rec0).copy(), np.asarray(live0).copy())
     for i in range(8, 40):                     # donating inserts churn on
         s = (int(rng.integers(6)), int(rng.integers(3)))
         host.add(s, float(i), float(i))
@@ -161,27 +161,30 @@ def test_donation_safety_held_views_survive_inserts():
         # interleaved reads through every accessor stay coherent
         assert len(dev) == len(host)
         assert dev.best()[0] == host.best()[0]
-    np.testing.assert_array_equal(np.asarray(feats0), before[0])
+    np.testing.assert_array_equal(np.asarray(states0), before[0])
     np.testing.assert_array_equal(np.asarray(ys0), before[1])
     np.testing.assert_array_equal(np.asarray(rec0), before[2])
+    np.testing.assert_array_equal(np.asarray(live0), before[3])
     _assert_snapshot_parity(host, dev)
 
 
 def test_refit_view_padding_is_inert():
-    """Bucket padding rows carry far features and zero weight: growing
+    """Bucket padding rows carry zero weight and are marked dead: growing
     the bucket must not change what a fused refit would see live."""
     _, dev = _pair()
     for i in range(5):
         dev.add((i, i % 3), float(i + 1), float(i))
-    feats, ys, rec = dev.refit_view(now=5.0)
+    states, ys, rec, live = dev.refit_view(now=5.0)
     n = len(dev)
-    assert feats.shape[0] >= n and feats.shape[0] == ys.shape[0]
+    assert states.shape[0] >= n and states.shape[0] == ys.shape[0]
     assert (np.asarray(rec[n:]) == 0.0).all()
-    assert (np.asarray(feats[n:]) >= 1e3).all()
-    bigger = dev.refit_view(now=5.0, m_bucket=2 * feats.shape[0])
+    assert (np.asarray(live[:n]) == 1.0).all()
+    assert (np.asarray(live[n:]) == 0.0).all()
+    bigger = dev.refit_view(now=5.0, m_bucket=2 * states.shape[0])
     np.testing.assert_array_equal(np.asarray(bigger[0][:n]),
-                                  np.asarray(feats[:n]))
+                                  np.asarray(states[:n]))
     assert (np.asarray(bigger[2][n:]) == 0.0).all()
+    assert (np.asarray(bigger[3][n:]) == 0.0).all()
 
 
 def test_empty_and_validation_errors_match_numpy_semantics():

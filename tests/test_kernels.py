@@ -309,21 +309,74 @@ def test_wkv6_kernel_direct_vs_ref():
                                atol=5e-4, rtol=1e-3)
 
 
+def _interp_space(F, rng):
+    """A mixed space whose encoding has F features: one categorical axis
+    of 3 values (3 features), the rest ordinal with 2-6 values."""
+    shape = (3,) + tuple(int(n) for n in rng.integers(2, 7, size=F - 3))
+    return shape, (True,) + (False,) * (F - 3)
+
+
+def _states(rng, shape, n):
+    return np.stack([rng.integers(k, size=n) for k in shape], axis=1)
+
+
+def _features(states, shape, categorical):
+    from repro.core import SpaceEncoding
+    return jnp.asarray(SpaceEncoding(shape, categorical).features(states))
+
+
 @pytest.mark.parametrize("kind", ["idw", "rbf"])
 @pytest.mark.parametrize("Q,M,F", [
     (5, 3, 7),          # tiny, everything padded
     (300, 37, 9),       # row counts straddling the query block
-    (130, 256, 130),    # feature dim over one lane width, M at a lane edge
+    (130, 256, 130),    # 130 axes: the one-hot spans several MXU passes
 ])
 def test_fused_interp_kernel_direct_vs_ref(kind, Q, M, F):
+    """Explicit query states against the reference on their features."""
     from repro.kernels.surrogate_distance import fused_interp
     rng = np.random.default_rng(Q + M + F)
-    xq = jnp.asarray(rng.normal(size=(Q, F)), jnp.float32)
-    xm = jnp.asarray(rng.normal(size=(M, F)), jnp.float32)
+    shape, cat = _interp_space(F, rng)
+    probes, queries = _states(rng, shape, M), _states(rng, shape, Q)
     y = jnp.asarray(rng.normal(size=(M,)), jnp.float32)
     w = jnp.asarray(rng.uniform(0.1, 1.0, size=(M,)), jnp.float32)
-    mean, dmin = fused_interp(xq, xm, y, w, kind=kind)
-    want_mean, want_dmin = ref.fused_interp_ref(xq, xm, y, w, kind=kind)
+    mean, dmin = fused_interp(jnp.asarray(probes), y, w, shape=shape,
+                              categorical=cat, queries=jnp.asarray(queries),
+                              kind=kind, block_q=128)
+    want_mean, want_dmin = ref.fused_interp_ref(
+        _features(queries, shape, cat), _features(probes, shape, cat), y, w,
+        kind=kind)
+    np.testing.assert_allclose(np.asarray(mean), np.asarray(want_mean),
+                               atol=2e-5, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(dmin), np.asarray(want_dmin),
+                               atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("kind", ["idw", "rbf"])
+@pytest.mark.parametrize("shape,qshape,offsets", [
+    ((2,) * 10, None, None),                 # every state, bf16-exact costs
+    ((5, 3, 4, 7), (3, 3, 2, 4), (2, 0, 1, 3)),   # a window, 3 bf16 parts
+])
+def test_fused_interp_enumerated_queries_vs_ref(kind, shape, qshape,
+                                                offsets):
+    """Queries enumerated inside the kernel from the block index and a
+    lane iota, shifted by window offsets, against the reference on the
+    features of the same states."""
+    from repro.kernels.surrogate_distance import fused_interp
+    rng = np.random.default_rng(len(shape))
+    cat = (False,) * len(shape)
+    probes = _states(rng, shape, 150)
+    y = jnp.asarray(rng.normal(size=(150,)), jnp.float32)
+    w = jnp.asarray(rng.uniform(0.1, 1.0, size=(150,)), jnp.float32)
+    mean, dmin = fused_interp(
+        jnp.asarray(probes), y, w, shape=shape, categorical=cat,
+        qshape=qshape, kind=kind, block_q=256,
+        offsets=None if offsets is None else jnp.asarray(offsets))
+    grid = np.indices(qshape or shape).reshape(len(shape), -1).T
+    if offsets is not None:
+        grid = grid + np.asarray(offsets)
+    want_mean, want_dmin = ref.fused_interp_ref(
+        _features(grid, shape, cat), _features(probes, shape, cat), y, w,
+        kind=kind)
     np.testing.assert_allclose(np.asarray(mean), np.asarray(want_mean),
                                atol=2e-5, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(dmin), np.asarray(want_dmin),
@@ -332,22 +385,32 @@ def test_fused_interp_kernel_direct_vs_ref(kind, Q, M, F):
 
 def test_fused_interp_zero_weight_rows_contribute_nothing():
     """The pow-2-bucket padding contract: rows with zero recency weight
-    (the device store's empty slots) must not shift the estimate, and
-    all-zero weights fall back to the recency-weighted global mean."""
+    and marked dead (the device store's empty slots) must not shift the
+    estimate nor be the nearest measurement, and all-zero weights fall
+    back to the recency-weighted global mean."""
     from repro.kernels.surrogate_distance import fused_interp
     rng = np.random.default_rng(7)
-    xq = jnp.asarray(rng.normal(size=(17, 5)), jnp.float32)
-    xm = jnp.asarray(rng.normal(size=(12, 5)), jnp.float32)
+    shape = (6, 5, 4)
+    probes = jnp.asarray(_states(rng, shape, 12))
+    queries = jnp.asarray(_states(rng, shape, 17))
     y = jnp.asarray(rng.normal(size=(12,)), jnp.float32)
     w = jnp.asarray(rng.uniform(0.2, 1.0, size=(12,)), jnp.float32)
-    base_mean, _ = fused_interp(xq, xm, y, w)
-    # append dead rows: far features, arbitrary y, zero weight
-    xm_pad = jnp.concatenate([xm, jnp.full((20, 5), 1e3, jnp.float32)])
-    y_pad = jnp.concatenate([y, jnp.full((20,), 99.0, jnp.float32)])
-    w_pad = jnp.concatenate([w, jnp.zeros((20,), jnp.float32)])
-    pad_mean, _ = fused_interp(xq, xm_pad, y_pad, w_pad)
+    base_mean, base_dmin = fused_interp(probes, y, w, shape=shape,
+                                        queries=queries)
+    # append dead rows: at the query states themselves, arbitrary y
+    probes_pad = jnp.concatenate([probes, queries[:12]])
+    y_pad = jnp.concatenate([y, jnp.full((12,), 99.0, jnp.float32)])
+    w_pad = jnp.concatenate([w, jnp.zeros((12,), jnp.float32)])
+    live = jnp.concatenate([jnp.ones((12,)), jnp.zeros((12,))])
+    pad_mean, pad_dmin = fused_interp(probes_pad, y_pad, w_pad, shape=shape,
+                                      queries=queries, valid=live)
     np.testing.assert_allclose(np.asarray(pad_mean), np.asarray(base_mean),
                                atol=2e-5, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(pad_dmin), np.asarray(base_dmin),
+                               atol=2e-5, rtol=1e-4)
+    zero_mean = fused_interp(probes, y, jnp.zeros((12,), jnp.float32),
+                             shape=shape, queries=queries, with_dmin=False)
+    np.testing.assert_allclose(np.asarray(zero_mean), 0.0, atol=1e-6)
 
 
 def test_kernel_ref_pairing_is_complete():

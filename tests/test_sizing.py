@@ -423,6 +423,133 @@ def test_sizing_controller_surrogate_source_runs_with_sparse_probes():
 
 
 # ---------------------------------------------------------------------------
+# The surrogate table on the device against a plain float64 reference.
+# ---------------------------------------------------------------------------
+
+#: the probe stream of ``repro.core.surrogate.PROBE_STREAM``, restated: the
+#: reference derives its keys without the program
+PROBE_STREAM = 0x70726F62
+#: relative gap allowed the device table from the float64 interpolation of
+#: float64 probe values: the probes are scored in float32 (their relative
+#: error here, at loads far from saturation, is a few 1e-7) and weighted
+#: and summed in float32 over 64 probes (a few 1e-7 more); 2e-5 leaves
+#: room above both and is 50 times below a bfloat16 table's gap
+SURROGATE_RTOL = 2e-5
+
+
+def _plain_probes(seed, r, size, n):
+    """The probe draw restated: ``2 n`` uniform draws from the round's
+    probe key, the first ``n`` distinct in draw order."""
+    import jax
+
+    key = jax.random.fold_in(jax.random.fold_in(jax.random.key(seed), r),
+                             PROBE_STREAM)
+    cand = np.asarray(jax.random.randint(key, (2 * n,), 0, size,
+                                         dtype=jnp.int32))
+    _, first = np.unique(cand, return_index=True)
+    return cand[np.sort(first)[:n]]
+
+
+def _plain_table(spec, mix, probes, states, eps=1e-9):
+    """Float64 Shepard interpolation (power 2) at flat ``states`` from the
+    numpy ground truth at flat ``probes``; an ordinal axis of n values
+    spans 1."""
+    shape = spec.space.shape
+    scale = np.asarray([1.0 / max(n - 1, 1) for n in shape])
+    y = np.asarray([spec.host_objective(spec.space.decode(
+        np.unravel_index(f, shape)), mix)["y"] for f in probes])
+    p = np.stack(np.unravel_index(probes, shape), 1) * scale
+    q = np.stack(np.unravel_index(states, shape), 1) * scale
+    d2 = ((q[:, None, :] - p[None, :, :]) ** 2).sum(-1)
+    k = 1.0 / (d2 + eps)
+    return (k @ y) / k.sum(1)
+
+
+def _surrogate_ctrl(seed=5, n_probe=64, mix=None, **kw):
+    spec = _spec(replica_counts=(1, 2))             # 4^6 = 4096 states
+    drift = DriftingMix(MIX_BROWSE, MIX_CHECKOUT, change_at=0, ramp=6)
+    ctrl = SizingController(
+        spec, drift if mix is None else mix,
+        objective_source=SurrogateSource(n_probe=n_probe),
+        steps_per_round=32, n_chains=8, seed=seed, **kw)
+    return spec, ctrl
+
+
+def test_device_surrogate_table_matches_float64_reference():
+    """The controller's device table of a round, built from 64 probes
+    drawn from the round's key, against the float64 interpolation of the
+    exact model at the reference's own draw, at every one of the 4,096
+    states; probes are reproduced exactly."""
+    spec, ctrl = _surrogate_ctrl()
+    size = spec.space.size()
+    for r in range(3):
+        ctrl.round()
+        mix = ctrl.decisions[-1].mix
+        table = np.asarray(ctrl._dtables[ctrl._mix_key(mix)], np.float64)
+        probes = _plain_probes(5, r, size, 64)
+        assert len(probes) == 64
+        want = _plain_table(spec, mix, probes, np.arange(size))
+        np.testing.assert_allclose(table, want, rtol=SURROGATE_RTOL)
+        # exact at the probes themselves: IDW reproduces a measurement
+        np.testing.assert_allclose(table[probes], want[probes],
+                                   rtol=SURROGATE_RTOL)
+
+
+def test_surrogate_committed_sizings_stay_within_the_bound():
+    """Each round commits the best visited state by its table; chain 0
+    starts at the previous incumbent, so by the float64 reference table
+    the committed sizing lies no higher than that incumbent, give or take
+    the table's error at both; and the committed y is the exact model's."""
+    spec, ctrl = _surrogate_ctrl(seed=11)
+    size, shape = spec.space.size(), spec.space.shape
+    prev = ctrl.incumbent
+    for r in range(6):
+        d = ctrl.round()
+        probes = _plain_probes(11, r, size, 64)
+        at = np.asarray([np.ravel_multi_index(prev, shape),
+                         np.ravel_multi_index(ctrl.incumbent, shape)])
+        y_prev, y_new = _plain_table(spec, d.mix, probes, at)
+        assert y_new <= y_prev * (1 + 2 * SURROGATE_RTOL)
+        assert d.y == pytest.approx(spec.host_objective(
+            spec.space.decode(ctrl.incumbent), d.mix)["y"], rel=1e-12)
+        prev = ctrl.incumbent
+    counts = ctrl.evaluation_counts()
+    assert counts["true_measures"] == 6 * (64 + 1)   # probes + re-measure
+    assert counts["surrogate_queries"] == 6 * size
+
+
+def test_surrogate_round_uploads_no_table_and_reads_back_only_the_packet(
+        monkeypatch):
+    """Under the sanitizer: no host table is built (the host source path
+    is never called), no device->host transfer beyond the decision packet
+    (which ``tolist`` reads, below its accounting) and no recompilation
+    after the first round."""
+    from repro.analysis import sanitize
+
+    def no_host_table(*a, **kw):
+        raise AssertionError("the host table path ran")
+
+    monkeypatch.setattr(SurrogateSource, "table", no_host_table)
+    pre_armed = sanitize.current().installed
+    san = sanitize.current() if pre_armed else sanitize.install()
+    mark = len(san.rounds)
+    try:
+        spec, ctrl = _surrogate_ctrl(seed=3)
+        ctrl.run(4)
+        rounds = [r for r in san.rounds[mark:]
+                  if r["controller"] == "SizingController"]
+        assert len(rounds) == 4
+        assert all(r["transfers"] == 0 for r in rounds)
+        assert rounds[0]["entries"]["surrogate_refit"]["calls"] == 1
+        assert all(d["compiles"] == 0
+                   for r in rounds[1:] for d in r["entries"].values())
+        assert ctrl._tables == {}
+    finally:
+        if not pre_armed:
+            sanitize.uninstall()
+
+
+# ---------------------------------------------------------------------------
 # Fleet integration: container tenants on a shared catalog.
 # ---------------------------------------------------------------------------
 

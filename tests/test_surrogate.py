@@ -340,6 +340,48 @@ def test_surrogate_source_near_argmin_with_fraction_of_measures():
     assert (y_at_est_argmin - table.min()) / table.min() <= 0.05
 
 
+@pytest.mark.parametrize("size,n", [(1 << 20, 1024), (4096, 64), (40, 64)])
+def test_draw_probes_matches_the_plain_draw(size, n):
+    """The device draw is the plain one from the same key: ``2 n``
+    uniform draws, the first ``n`` distinct in draw order, and weight 0
+    (state 0) on the slots the draws leave over (40 states cannot give
+    64 distinct probes)."""
+    from repro.core.surrogate import draw_probes
+
+    key = jax.random.key(2_147_483_659 % (1 << 32))
+    flat, weight = draw_probes(key, size, n)
+    cand = np.asarray(jax.random.randint(key, (2 * n,), 0, size,
+                                         dtype=jnp.int32))
+    _, first = np.unique(cand, return_index=True)
+    want = cand[np.sort(first)[:n]]
+    flat, weight = np.asarray(flat), np.asarray(weight)
+    k = len(want)
+    assert k == n if size >= 2 * n else k <= size
+    np.testing.assert_array_equal(flat[:k], want)
+    assert (weight[:k] == 1.0).all() and (weight[k:] == 0.0).all()
+    assert (flat[k:] == 0).all()
+    assert len(set(flat[:k].tolist())) == k
+
+
+def test_device_table_refuses_what_it_cannot_build():
+    """A space with invalid states and a host recycle store belong to the
+    host table path; the device path says so instead of ignoring them."""
+    dims = (Dimension("a", tuple(range(8))), Dimension("b", tuple(range(4))))
+    score = lambda flat: flat.astype(jnp.float32)
+    with pytest.raises(ValueError, match="valid"):
+        SurrogateSource(n_probe=4).device_table(
+            ConfigSpace(dims, lambda cfg: cfg["a"] < 7), score,
+            jax.random.key(0))
+    free = ConfigSpace(dims)
+    with pytest.raises(ValueError, match="host table path"):
+        SurrogateSource(n_probe=4, recycle_store=MeasurementStore(2)
+                        ).device_table(free, score, jax.random.key(0))
+    src = SurrogateSource(n_probe=4)
+    table = np.asarray(src.device_table(free, score, jax.random.key(0)))
+    assert table.shape == (32,)
+    assert src.counts() == {"true_measures": 4, "surrogate_queries": 32}
+
+
 def test_fleet_controller_with_surrogate_source_saves_measures():
     catalog = EC2_CATALOG_ADJUSTED.with_capacities(
         {f: 300.0 for f in EC2_CATALOG_ADJUSTED.names()})

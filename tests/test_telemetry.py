@@ -609,6 +609,25 @@ def test_sizing_round_is_tiled_by_dispatch_sync_measure_commit():
         ["sizing.refit", "sizing.anneal"]] * 3
 
 
+def test_surrogate_sizing_round_counts_probes_and_interpolated_states():
+    """With a SurrogateSource each round that builds a table counts its
+    probes and its interpolated states, and the table program is
+    enqueued inside the round's ``sizing.refit`` span."""
+    from repro.core import SurrogateSource
+
+    base = _sizing()
+    ctl = SizingController(base.spec, lambda r: {"r": 20.0 + r},
+                           objective_source=SurrogateSource(n_probe=8),
+                           steps_per_round=8, n_chains=4, seed=0)
+    with telemetry.session() as tel:
+        ctl.run(3)
+    counters = tel.metrics.snapshot(prefix="sizing")["counters"]
+    assert counters["sizing/probes"] == 3 * 8
+    assert counters["sizing/interp_states"] == 3 * base.space.size()
+    assert _children(tel.spans.spans(), "sizing.dispatch", 1) == [
+        ["sizing.refit", "sizing.anneal"]] * 3
+
+
 def test_sizing_host_path_waits_in_its_sync_span():
     ctl = _sizing()
     ctl.device_loop = False

@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
     SingleDeviceSharding
 
-from repro.core import EC2_CATALOG_ADJUSTED, SpaceEncoding, make_ec2_space
+from repro.core import EC2_CATALOG_ADJUSTED, make_ec2_space
 from repro.core.annealing import _fleet_nd_jit, _fleet_shard_jit
 from repro.kernels.sizing_latency import sizing_latency
 from repro.kernels.surrogate_distance import fused_interp, pairwise_sqdist
@@ -31,6 +31,8 @@ from repro.kernels.surrogate_distance import fused_interp, pairwise_sqdist
 M_CAP = 8192
 #: A refit's query block: one 1024-state window bucket.
 Q = 1024
+#: Measurements a round of the 1,048,576-state surrogate table probes.
+N_PROBE = 1024
 #: Tenants of the largest trace-fleet replay, and its chain steps.
 FLEET_T, FLEET_STEPS = 1024, 32
 
@@ -67,9 +69,14 @@ def _f32(shape, sharding):
 
 def _scale_feature_dim() -> int:
     """Feature width of the 1,179,648-state surrogate-scale space."""
+    from repro.core import SpaceEncoding
+    return SpaceEncoding.from_space(_scale_space()).feature_dim
+
+
+def _scale_space():
     from benchmarks.surrogate_scale import scale_problem
     space, _ = scale_problem()
-    return SpaceEncoding.from_space(space).feature_dim
+    return space
 
 
 def _trace_fleet_space():
@@ -79,13 +86,58 @@ def _trace_fleet_space():
 
 @pytest.mark.parametrize("kind", ["idw", "rbf"])
 def test_fused_interp_compiles_at_store_capacity(one_chip, kind):
-    F = _scale_feature_dim()
-    fn = jax.jit(lambda xq, xm, y, w: fused_interp(
-        xq, xm, y, w, kind=kind, interpret=False))
-    compiled = fn.lower(_f32((Q, F), one_chip), _f32((M_CAP, F), one_chip),
-                        _f32((M_CAP,), one_chip),
-                        _f32((M_CAP,), one_chip)).compile()
+    """The annealer's window refit: every state of a window enumerated in
+    the kernel, against the store's full capacity bucket."""
+    from repro.core import window_space
+    space = _scale_space()
+    sub, _ = window_space(space, (0,) * len(space.shape), 6)
+    nd = len(space.shape)
+    fn = jax.jit(lambda p, y, w, live, off: fused_interp(
+        p, y, w, shape=space.shape,
+        categorical=tuple(d.kind == "categorical" for d in space.dimensions),
+        qshape=sub.shape, offsets=off, valid=live, kind=kind,
+        interpret=False))
+    i32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                             sharding=one_chip)
+    compiled = fn.lower(i32((M_CAP, nd)), _f32((M_CAP,), one_chip),
+                        _f32((M_CAP,), one_chip), _f32((M_CAP,), one_chip),
+                        i32((nd,))).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_surrogate_table_compiles_at_boutique_1m(one_chip, monkeypatch):
+    """The surrogate table program of the 1,048,576-state Boutique
+    sizing: probe draw, the Erlang-C kernel on the probes and the
+    interpolation kernel over every state, one program.  Its kernels pick
+    interpret mode by the default backend, so the backend reads as the
+    chip's while the program lowers."""
+    import json
+    import pathlib
+
+    from repro.core import SizingSpace
+    from repro.core.surrogate import _surrogate_table_jit
+    from repro.workloads.microservice import (
+        ContainerSize, MicroserviceDAG, RequestClass, ServiceTier)
+
+    cfg = json.loads((pathlib.Path(__file__).parents[1] / "bench" / "configs"
+                      / "boutique-sizing-1m.json").read_text())
+    spec = SizingSpace(
+        MicroserviceDAG(tuple(ServiceTier(**t) for t in cfg["tiers"]),
+                        tuple(tuple(e) for e in cfg["edges"]),
+                        tuple(RequestClass(**k) for k in cfg["classes"])),
+        sizes=tuple(ContainerSize(**s) for s in cfg["sizes"]),
+        replica_counts=tuple(cfg["replica_counts"]), sat_s=cfg["sat_s"])
+    enc = spec.space.encoded(max_size=spec.space.size())
+    assert spec.space.size() == 1 << 20 and enc.valid_mask is None
+    fn = _surrogate_table_jit(enc.shape, enc.categorical, N_PROBE, "idw",
+                              0.25, 2.0, 1e-9, spec._probe_scores[True])
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = fn.lower(
+        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip),
+        _f32((len(cfg["classes"]),), one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
 
 
 def test_pairwise_sqdist_compiles(one_chip):
