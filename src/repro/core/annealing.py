@@ -699,15 +699,31 @@ def random_valid_states(
 ) -> jax.Array:
     """(n, ndim) int32 index vectors uniform over the VALID region."""
     enc = _as_encoded(space)
+    return draw_states(key, enc.shape, n, valid_indices(enc))
+
+
+def valid_indices(enc: EncodedSpace) -> jax.Array | None:
+    """Row-major flat indices of ``enc``'s valid states as an int32 device
+    array, or None when every state is valid."""
     if enc.valid_mask is None:
-        maxs = jnp.asarray(enc.shape, jnp.int32)
-        return jax.random.randint(key, (n, enc.ndim), 0, maxs,
-                                  dtype=jnp.int32)
+        return None
     flat = np.flatnonzero(enc.valid_mask.reshape(-1))
     if flat.size == 0:
         raise ValueError("space has no valid states")
-    picks = jax.random.choice(key, jnp.asarray(flat, jnp.int32), (n,))
-    return jnp.stack(jnp.unravel_index(picks, enc.shape), axis=-1) \
+    return jnp.asarray(flat, jnp.int32)
+
+
+def draw_states(key: jax.Array, shape: tuple[int, ...], n: int,
+                valid_idx: jax.Array | None = None) -> jax.Array:
+    """(n, ndim) int32 index vectors uniform over a space of ``shape``, or
+    over ``valid_idx`` (flat indices, :func:`valid_indices`) when given.
+    Traceable: a jitted caller passes ``valid_idx`` as an argument."""
+    if valid_idx is None:
+        maxs = jnp.asarray(shape, jnp.int32)
+        return jax.random.randint(key, (n, len(shape)), 0, maxs,
+                                  dtype=jnp.int32)
+    picks = jax.random.choice(key, valid_idx, (n,))
+    return jnp.stack(jnp.unravel_index(picks, shape), axis=-1) \
               .astype(jnp.int32)
 
 
@@ -769,6 +785,7 @@ def anneal_fleet(
     per_chain_tables: bool = False,
     extra_costs: jax.Array | np.ndarray | None = None,
     coupling_penalty: Callable[[EncodedSpace, int], np.ndarray] | None = None,
+    chain_keys: jax.Array | None = None,
 ) -> dict[str, jax.Array]:
     """A fleet of N-dim chains in ONE jitted call (paper Figs. 4/5/10 at
     scale: seeds x temperatures x tenants).
@@ -793,6 +810,13 @@ def anneal_fleet(
     after-the-fact clamp.  ``coupling_penalty`` is the callable form of the
     same hook: ``coupling_penalty(encoded_space, n_chains)`` must return
     such an array (mutually exclusive with ``extra_costs``).
+
+    ``chain_keys``: the (C,) per-chain keys, already split (a caller that
+    draws them inside its own program, as the sizing round does); ``key``
+    then seeds only the inits drawn when ``inits`` is None.  Without it
+    both come from ``key`` by two splits.  Given device arrays of the
+    expected shapes and dtypes, this function binds no eager op: it only
+    launches :func:`_fleet_nd_jit`.
 
     Returns ``{"states": (C, n_steps, ndim), "ys": (C, n_steps),
     "accepts": (C, n_steps), "inits": (C, ndim)}`` — inits included so
@@ -829,8 +853,11 @@ def anneal_fleet(
     else:
         taus_b = jnp.broadcast_to(taus_arr, (n_chains, n_steps))
 
-    key, k_init = jax.random.split(key)
-    keys = jax.random.split(key, n_chains)
+    if chain_keys is None:
+        key, k_init = jax.random.split(key)
+        keys = jax.random.split(key, n_chains)
+    else:
+        k_init, keys = key, chain_keys
     if inits is None:
         inits = random_valid_states(k_init, enc, n_chains)
     else:

@@ -329,7 +329,6 @@ def sizing_table_device(
     stays on device; :class:`SizingController`'s device loop reshapes it
     straight into :func:`repro.core.annealing.anneal_fleet`."""
     import jax
-    import jax.numpy as jnp
 
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
@@ -338,7 +337,7 @@ def sizing_table_device(
     if rates.shape != (len(spec.dag.classes),):
         raise ValueError(
             f"rates shape {rates.shape} != ({len(spec.dag.classes)},)")
-    return spec._table_jit(jnp.asarray(rates, jnp.float32),
+    return spec._table_jit(np.asarray(rates, np.float32),
                            use_kernel=bool(use_kernel))
 
 
@@ -451,6 +450,61 @@ def _sizing_select_jit(shape: tuple, topk: int):
     return select
 
 
+def _sizing_prep_args(r: int, incumbent, taus) -> np.ndarray:
+    """The round index, the incumbent and the temperatures' float32 bits
+    in one int32 vector, :func:`_sizing_prep_jit`'s one upload a round:
+    each host array passed to a jitted call is a transfer of its own, and
+    on a TPU host one costs a good part of what a launch does."""
+    incumbent = np.asarray(incumbent, np.int32)
+    taus = np.asarray(taus, np.float32)
+    packed = np.empty(1 + incumbent.size + taus.size, np.int32)
+    packed[0] = r
+    packed[1:1 + incumbent.size] = incumbent
+    packed[1 + incumbent.size:] = taus.view(np.int32)
+    return packed
+
+
+@functools.cache
+def _sizing_prep_jit(shape: tuple, n_chains: int):
+    """Jitted preparation of one device-loop round: everything the round
+    feeds its table, anneal and select programs, drawn by the same
+    threefry calls in the same order as the eager sequence it replaces —
+    ``fold_in`` of the round, ``split`` into the inits' and the chains'
+    keys, the inits (:func:`repro.core.annealing.draw_states`) with row 0
+    at the incumbent, then :func:`repro.core.annealing.anneal_fleet`'s own
+    ``split`` and ``split(n_chains)`` — so decisions are bit-identical.
+
+    ``prep(base_key, packed, valid_idx=None)``: ``packed`` from
+    :func:`_sizing_prep_args` (the round index traced: one program serves
+    every round), ``valid_idx`` the valid states' flat indices as a
+    device argument (None: every state is valid).  Returns ``(key_r,
+    k_init, chain_keys, inits, taus_b)``: the round's key (the surrogate
+    table's probes are drawn from it), ``anneal_fleet``'s init key and
+    (n_chains,) chain keys, the (n_chains, ndim) inits and the
+    (n_chains, steps) temperatures."""
+    import jax
+    import jax.numpy as jnp
+
+    from .annealing import draw_states
+
+    ndim = len(shape)
+
+    @jax.jit
+    def prep(base_key, packed, valid_idx=None):
+        r, incumbent = packed[0], packed[1:1 + ndim]
+        taus = jax.lax.bitcast_convert_type(packed[1 + ndim:], jnp.float32)
+        key_r = jax.random.fold_in(base_key, r)
+        k_init, k_run = jax.random.split(key_r)
+        inits = draw_states(k_init, shape, n_chains, valid_idx)
+        inits = inits.at[0].set(incumbent)
+        k_chains, k_fleet_init = jax.random.split(k_run)
+        chain_keys = jax.random.split(k_chains, n_chains)
+        taus_b = jnp.broadcast_to(taus, (n_chains, taus.shape[0]))
+        return key_r, k_fleet_init, chain_keys, inits, taus_b
+
+    return prep
+
+
 # ---------------------------------------------------------------------------
 # The online controller.
 # ---------------------------------------------------------------------------
@@ -518,6 +572,8 @@ class SizingController(ControllerMixin):
     ):
         import jax
 
+        from .annealing import valid_indices
+
         if steps_per_round < 1 or n_chains < 1:
             raise ValueError("steps_per_round and n_chains must be >= 1")
         if measure_topk < 1:
@@ -541,6 +597,7 @@ class SizingController(ControllerMixin):
             self.space.size(), TABULATE_CAP))
         self._shape = self._enc.shape
         self._key = jax.random.key(seed)
+        self._valid_idx = valid_indices(self._enc)
         self.steps_per_round = int(steps_per_round)
         self.n_chains = int(n_chains)
         self._schedule = AdaptiveReheat(
@@ -622,14 +679,17 @@ class SizingController(ControllerMixin):
             if src is None:
                 self._dtables[key] = sizing_table_device(self.spec, rates)
                 self._count_measures(self.space.size())
+                if metrics.get() is not None:
+                    metrics.inc("sizing/programs_enqueued")
             elif isinstance(src, SurrogateSource):
                 score = self.spec._probe_scores[
                     jax.default_backend() == "tpu"]
                 self._dtables[key] = src.device_table(
-                    self._enc, score, round_key, jnp.asarray(
-                        self.spec.dag.rates_array(rates), jnp.float32))
+                    self._enc, score, round_key, np.asarray(
+                        self.spec.dag.rates_array(rates), np.float32))
                 self._count_measures(src.n_probe)
                 if metrics.get() is not None:
+                    metrics.inc("sizing/programs_enqueued")
                     metrics.inc("sizing/probes", src.n_probe)
                     metrics.inc("sizing/interp_states", self.space.size())
             else:
@@ -677,31 +737,28 @@ class SizingController(ControllerMixin):
         taus = self._schedule.tau_array(n0, self.steps_per_round)
 
         if self.device_loop:
-            import jax.numpy as jnp
-
-            # device-resident phase: fused table -> anneal -> top-K
-            # without a bulk host round-trip; only the (topk, ndim)
-            # decision packet is read back
+            # device-resident phase: prep -> table (on a cache miss) ->
+            # anneal -> top-K, three or four programs enqueued and no
+            # eager op; only the (topk, ndim) decision packet is read back
             with span("sizing.dispatch", cat="sizing"):
-                key_r = jax.random.fold_in(self._key, r)
-                k_init, k_run = jax.random.split(key_r)
+                key_r, k_init, chain_keys, inits_d, taus_d = \
+                    _sizing_prep_jit(self._shape, self.n_chains)(
+                        self._key, _sizing_prep_args(r, self.incumbent, taus),
+                        self._valid_idx)
                 with span("sizing.refit", cat="sizing"):
                     table_d = self._dtable_for(rates, key_r)
-                inits_d = random_valid_states(
-                    k_init, self._enc, self.n_chains).astype(jnp.int32)
-                inits_d = inits_d.at[0].set(
-                    jnp.asarray(self.incumbent, jnp.int32))
                 with span("sizing.anneal", cat="sizing"):
                     out = anneal_fleet(
-                        k_run, self._enc, table_d, self.steps_per_round,
-                        jnp.broadcast_to(
-                            jnp.asarray(taus, jnp.float32),
-                            (self.n_chains, self.steps_per_round)),
-                        inits=inits_d, n_chains=self.n_chains)
+                        k_init, self._enc, table_d, self.steps_per_round,
+                        taus_d, inits=inits_d, n_chains=self.n_chains,
+                        chain_keys=chain_keys)
                 sel, explored_d = _sizing_select_jit(
                     self._shape, self.measure_topk)(
                     inits_d, out["states"], table_d, out["ys"],
                     out["accepts"])
+            if metrics.get() is not None:
+                # prep, anneal and select; _dtable_for counts a table's
+                metrics.inc("sizing/programs_enqueued", 3)
             # .tolist()/bool() read the small decision packet — the one
             # host pull of the round, below the sanitizer's bulk-transfer
             # accounting (np.asarray / device_get), and the only place

@@ -383,6 +383,60 @@ def test_sizing_controller_is_deterministic_under_seed():
     assert runs[0] == runs[1]
 
 
+@pytest.mark.parametrize("masked", [False, True])
+def test_sizing_prep_program_matches_the_eager_sequence(masked):
+    """The device loop's prep program draws the round key, the chains'
+    keys, the inits and the temperatures bit for bit as the eager
+    sequence it replaced: ``fold_in``, ``split``, the inits (uniform, or
+    over the valid region), row 0 set to the incumbent, then
+    ``anneal_fleet``'s ``split`` and ``split(n_chains)``."""
+    import jax
+
+    from repro.core.annealing import valid_indices
+    from repro.core.sizing import _sizing_prep_args, _sizing_prep_jit
+    from repro.core.state import EncodedSpace
+
+    shape, n, steps = (3, 4, 2, 5), 16, 12
+    mask = None
+    if masked:
+        grid = np.indices(shape)
+        mask = (grid[0] + grid[1] + grid[3]) % 3 != 0
+    enc = EncodedSpace(shape, (False,) * len(shape), mask)
+    incumbent = np.asarray((2, 1, 0, 4), np.int32)
+    taus = np.linspace(0.5, 4.0, steps).astype(np.float32)
+    base = jax.random.key(3150000211)
+    prep = _sizing_prep_jit(shape, n)
+    for r in (0, 7, 123456):
+        key_r = jax.random.fold_in(base, r)
+        k_init, k_run = jax.random.split(key_r)
+        if mask is None:
+            inits = jax.random.randint(
+                k_init, (n, len(shape)), 0, jnp.asarray(shape, jnp.int32),
+                dtype=jnp.int32)
+        else:
+            picks = jax.random.choice(
+                k_init, jnp.asarray(np.flatnonzero(mask), jnp.int32), (n,))
+            inits = jnp.stack(jnp.unravel_index(picks, shape),
+                              axis=-1).astype(jnp.int32)
+        inits = inits.at[0].set(jnp.asarray(incumbent, jnp.int32))
+        k_chains, k_fleet_init = jax.random.split(k_run)
+        chain_keys = jax.random.split(k_chains, n)
+        taus_b = jnp.broadcast_to(jnp.asarray(taus, jnp.float32),
+                                  (n, steps))
+
+        got = prep(base, _sizing_prep_args(r, incumbent, taus),
+                   valid_indices(enc))
+        want = (key_r, k_fleet_init, chain_keys, inits, taus_b)
+        for g, w in zip(got[:3], want[:3]):
+            np.testing.assert_array_equal(jax.random.key_data(g),
+                                          jax.random.key_data(w))
+        for g, w in zip(got[3:], want[3:]):
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        if mask is not None:
+            assert mask[tuple(np.asarray(got[3])[1:].T)].all()
+
+
 def test_sizing_controller_refuses_large_space_without_source():
     spec = _spec(sizes=(ContainerSize("s", 1, 2.0),
                         ContainerSize("m", 2, 4.0),

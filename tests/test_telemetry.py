@@ -628,6 +628,43 @@ def test_surrogate_sizing_round_counts_probes_and_interpolated_states():
         ["sizing.refit", "sizing.anneal"]] * 3
 
 
+@pytest.mark.parametrize("table,programs", [
+    ("cache_hit", 3), ("build", 4), ("surrogate", 4)])
+def test_warm_sizing_round_binds_no_eager_primitive(monkeypatch, table,
+                                                    programs):
+    """A warmed device-loop round only launches its programs — prep,
+    anneal and select, plus the table program when it builds a table —
+    and binds no primitive eagerly (a jit call on its slow path would
+    bind one too); ``sizing/programs_enqueued`` counts the launches."""
+    from jax._src import core as jax_core
+
+    from repro.core import SurrogateSource
+
+    base = _sizing()
+    if table == "cache_hit":
+        ctl = base
+    else:
+        ctl = SizingController(
+            base.spec, lambda r: {"r": 20.0 + r},
+            objective_source=(SurrogateSource(n_probe=8)
+                              if table == "surrogate" else None),
+            steps_per_round=8, n_chains=4, seed=0)
+    ctl.run(3)
+    bound = []
+    real = jax_core.EvalTrace.process_primitive
+
+    def count(self, primitive, tracers, params):
+        bound.append(primitive.name)
+        return real(self, primitive, tracers, params)
+
+    monkeypatch.setattr(jax_core.EvalTrace, "process_primitive", count)
+    with telemetry.session() as tel:
+        ctl.round()
+    assert bound == []
+    counters = tel.metrics.snapshot(prefix="sizing")["counters"]
+    assert counters["sizing/programs_enqueued"] == programs
+
+
 def test_sizing_host_path_waits_in_its_sync_span():
     ctl = _sizing()
     ctl.device_loop = False

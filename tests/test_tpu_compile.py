@@ -133,9 +133,17 @@ def test_surrogate_table_compiles_at_boutique_1m(one_chip, monkeypatch):
                               0.25, 2.0, 1e-9, spec._probe_scores[True])
     key = jax.eval_shape(lambda: jax.random.key(0))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = fn.lower(
-        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip),
-        _f32((len(cfg["classes"]),), one_chip)).compile()
+    # the jitted kernel wrappers of ``kernels/ops.py`` keep their traces
+    # whatever the backend reads: drop those made on the CPU (a 1,024-state
+    # Boutique table holds the interpreted kernel at these shapes) before
+    # the program lowers for the chip, and those made for the chip after
+    jax.clear_caches()
+    try:
+        compiled = fn.lower(
+            jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip),
+            _f32((len(cfg["classes"]),), one_chip)).compile()
+    finally:
+        jax.clear_caches()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 2
 
