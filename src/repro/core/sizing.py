@@ -24,15 +24,17 @@ Pieces:
 
 * :class:`SizingController` — the online loop on
   :class:`repro.core.procurement.ControllerMixin`: each control round
-  reads the (drifting) request mix, refreshes the objective table
-  (cached per mix), anneals a compiled chain fleet from the incumbent,
-  re-measures the chosen sizing on the numpy ground-truth model, and
-  feeds drift detection -> reheats.  Tables come from the batched
-  evaluator by default; spaces beyond the 200k tabulation cap must
-  inject a :class:`repro.core.surrogate.SurrogateSource` (probe and
-  interpolate), exactly like the other controllers.  On the device loop
-  its table is one device program: probes drawn from the round's key,
-  scored by the same Erlang-C path, every state interpolated.
+  reads the (drifting) request mix, refreshes the device objective
+  table (cached per mix), anneals a compiled chain fleet from the
+  incumbent and selects the top-K visited sizings on the device, reads
+  back only that packet, re-measures it on the numpy ground-truth model,
+  and feeds drift detection -> reheats.  Tables come from the fused
+  enumeration + Erlang-C program by default; spaces beyond the 200k
+  tabulation cap must inject a
+  :class:`repro.core.surrogate.SurrogateSource` (probe and interpolate),
+  exactly like the other controllers.  Its table is one device program:
+  probes drawn from the round's key, scored by the same Erlang-C path,
+  every state interpolated.
 
 * Fleet integration — :class:`MicroserviceEvaluator` +
   :func:`microservice_config_fn` let microservice tenants join a
@@ -326,7 +328,7 @@ def sizing_table_device(
     """Device-resident flat objective table for one request mix —
     candidate enumeration fused with the Erlang-C kernel in one jitted
     call (:attr:`SizingSpace._table_jit`).  The (size,) float32 result
-    stays on device; :class:`SizingController`'s device loop reshapes it
+    stays on device; :class:`SizingController`'s round reshapes it
     straight into :func:`repro.core.annealing.anneal_fleet`."""
     import jax
 
@@ -390,12 +392,14 @@ def full_grid(space: ConfigSpace) -> np.ndarray:
 def _sizing_select_jit(shape: tuple, topk: int):
     """Jitted on-device top-K candidate selection + exploration flag.
 
-    Replicates the host path exactly: stable argsort of the visited
-    states' table estimates (ties break by visit position, chain-major),
-    first-``topk``-distinct dedup, plus the per-chain accepted-uphill
-    reduction of :meth:`repro.core.procurement.ControllerMixin.
-    explored_flags`.  Returns ((topk, ndim) int32 states with -1
-    sentinel rows, scalar explored flag)."""
+    A stable argsort of the visited states' table estimates (ties break
+    by visit position, chain-major), first-``topk``-distinct dedup, plus
+    the per-chain accepted-uphill reduction of
+    :meth:`repro.core.procurement.ControllerMixin.explored_flags`.  Its
+    numpy oracle is
+    ``tests/test_sizing.py::test_sizing_select_matches_host_topk``.
+    Returns ((topk, ndim) int32 states with -1 sentinel rows, scalar
+    explored flag)."""
     import jax
     import jax.numpy as jnp
 
@@ -466,7 +470,7 @@ def _sizing_prep_args(r: int, incumbent, taus) -> np.ndarray:
 
 @functools.cache
 def _sizing_prep_jit(shape: tuple, n_chains: int):
-    """Jitted preparation of one device-loop round: everything the round
+    """Jitted preparation of one sizing round: everything the round
     feeds its table, anneal and select programs, drawn by the same
     threefry calls in the same order as the eager sequence it replaces —
     ``fold_in`` of the round, ``split`` into the inits' and the chains'
@@ -540,16 +544,17 @@ class SizingController(ControllerMixin):
     a signal — covers *unannounced* drift, e.g. a schedule the
     controller cannot see).
 
-    ``objective_source=None`` tabulates via ONE
-    :func:`evaluate_sizing_batch` whole-grid call (counted into
-    ``true_measures`` — the batched analog of ``ExhaustiveSource``) and
-    refuses spaces beyond the 200k cap; inject a
+    ``objective_source=None`` tabulates via ONE fused whole-grid device
+    program (:func:`sizing_table_device`, counted into ``true_measures``
+    — the batched analog of ``ExhaustiveSource``) and refuses spaces
+    beyond the 200k cap; inject a
     :class:`repro.core.surrogate.SurrogateSource` to probe-and-
-    interpolate large DAGs (on the device loop: ``n_probe`` states drawn
-    from each round's key and scored on the device, see
-    :meth:`SurrogateSource.device_table`; on the host path: through
-    ``host_objective``), or an ``ExhaustiveSource`` to force the scalar
-    one-state-at-a-time path.
+    interpolate large DAGs (``n_probe`` states drawn from each round's
+    key and scored on the device, see
+    :meth:`SurrogateSource.device_table`; a source with a
+    ``recycle_store`` is refused, its warm start is host-only), or an
+    ``ExhaustiveSource`` to force the scalar one-state-at-a-time path
+    (its host table uploaded once per mix).
     """
 
     def __init__(
@@ -568,7 +573,6 @@ class SizingController(ControllerMixin):
         measure_topk: int = 1,
         eval_workers: int | None = None,
         recycle_store: "Any | None" = None,
-        device_loop: bool = True,
     ):
         import jax
 
@@ -589,6 +593,12 @@ class SizingController(ControllerMixin):
                 f"space has {self.space.size()} states — beyond the "
                 f"{TABULATE_CAP} tabulation cap; inject a SurrogateSource "
                 f"(probe and interpolate) to size this DAG")
+        if (isinstance(objective_source, SurrogateSource)
+                and objective_source.recycle_store is not None):
+            raise ValueError(
+                "a SurrogateSource with a recycle_store builds its tables "
+                "on the host (SurrogateSource.table); the sizing round "
+                "builds them on the device, so pass one without it")
         self.measure_topk = int(measure_topk)
         self.eval_workers = eval_workers
         self.recycle_store = recycle_store
@@ -605,11 +615,7 @@ class SizingController(ControllerMixin):
             relax=0.9)
         self._detector = PageHinkley() if detector else None
         self._reheat_pending = False
-        self._tables: dict[tuple, np.ndarray] = {}
-        # device-resident control loop (tentpole): table enumeration +
-        # scoring fused on device, anneal + top-K selection on device,
-        # only the (topk, ndim) decision packet read back
-        self.device_loop = bool(device_loop)
+        # mix key -> flat (size,) float32 device table
         self._dtables: dict[tuple, Any] = {}
         self._round = 0
         if init is None:
@@ -634,46 +640,21 @@ class SizingController(ControllerMixin):
     #: never recur exactly.
     TABLE_CACHE = 8
 
-    def _table_for(self, rates: Mapping[str, float]) -> np.ndarray:
-        """Flat (size,) objective table for one request mix; cached for
-        the last :attr:`TABLE_CACHE` distinct mixes (stalest evicted)."""
-        key = self._mix_key(rates)
-        if key in self._tables:
-            self._tables[key] = self._tables.pop(key)   # refresh LRU order
-        else:
-            if self.objective_source is None:
-                res = evaluate_sizing_batch(
-                    self.spec, full_grid(self.space), rates)
-                self._count_measures(self.space.size())
-                self._tables[key] = res["y"]
-            else:
-                def fn(decoded: dict[str, Any]) -> float:
-                    self._count_measures(1)
-                    return float(
-                        self.spec.host_objective(decoded, rates)["y"])
-
-                table = np.asarray(self.objective_source.table(
-                    self.space, fn, valid_mask=self._enc.valid_mask),
-                    np.float64)
-                self._tables[key] = table.reshape(-1)
-            while len(self._tables) > self.TABLE_CACHE:
-                self._tables.pop(next(iter(self._tables)))
-        return self._tables[key]
-
     def _dtable_for(self, rates: Mapping[str, float], round_key):
         """Device flat (size,) objective table for one mix — the fused
         enumeration+scoring jit when tables come from the batched
         evaluator; with a :class:`SurrogateSource`, its device program
         (probes drawn from ``round_key``, scored by the same Erlang-C
         path and interpolated, nothing crossing to the host); a one-way
-        host->device upload when another ``objective_source`` builds
-        them.  Same LRU policy as :meth:`_table_for`."""
+        host->device upload of the table another ``objective_source``
+        builds through ``host_objective``.  Cached for the last
+        :attr:`TABLE_CACHE` distinct mixes (stalest evicted)."""
         import jax
         import jax.numpy as jnp
 
         key = self._mix_key(rates)
         if key in self._dtables:
-            self._dtables[key] = self._dtables.pop(key)
+            self._dtables[key] = self._dtables.pop(key)   # refresh LRU order
         else:
             src = self.objective_source
             if src is None:
@@ -693,8 +674,16 @@ class SizingController(ControllerMixin):
                     metrics.inc("sizing/probes", src.n_probe)
                     metrics.inc("sizing/interp_states", self.space.size())
             else:
+                def fn(decoded: dict[str, Any]) -> float:
+                    self._count_measures(1)
+                    return float(
+                        self.spec.host_objective(decoded, rates)["y"])
+
+                table = np.asarray(src.table(
+                    self.space, fn, valid_mask=self._enc.valid_mask),
+                    np.float64)
                 self._dtables[key] = jnp.asarray(
-                    self._table_for(rates), jnp.float32)
+                    table.reshape(-1), jnp.float32)
             while len(self._dtables) > self.TABLE_CACHE:
                 self._dtables.pop(next(iter(self._dtables)))
         return self._dtables[key]
@@ -721,9 +710,7 @@ class SizingController(ControllerMixin):
         return d
 
     def _round_impl(self) -> SizingDecision:
-        import jax
-
-        from .annealing import anneal_fleet, random_valid_states
+        from .annealing import anneal_fleet
 
         r = self._round
         rates = self._mix_at(r)
@@ -736,102 +723,49 @@ class SizingController(ControllerMixin):
             reheated = True
         taus = self._schedule.tau_array(n0, self.steps_per_round)
 
-        if self.device_loop:
-            # device-resident phase: prep -> table (on a cache miss) ->
-            # anneal -> top-K, three or four programs enqueued and no
-            # eager op; only the (topk, ndim) decision packet is read back
-            with span("sizing.dispatch", cat="sizing"):
-                key_r, k_init, chain_keys, inits_d, taus_d = \
-                    _sizing_prep_jit(self._shape, self.n_chains)(
-                        self._key, _sizing_prep_args(r, self.incumbent, taus),
-                        self._valid_idx)
-                with span("sizing.refit", cat="sizing"):
-                    table_d = self._dtable_for(rates, key_r)
-                with span("sizing.anneal", cat="sizing"):
-                    out = anneal_fleet(
-                        k_init, self._enc, table_d, self.steps_per_round,
-                        taus_d, inits=inits_d, n_chains=self.n_chains,
-                        chain_keys=chain_keys)
-                sel, explored_d = _sizing_select_jit(
-                    self._shape, self.measure_topk)(
-                    inits_d, out["states"], table_d, out["ys"],
-                    out["accepts"])
-            if metrics.get() is not None:
-                # prep, anneal and select; _dtable_for counts a table's
-                metrics.inc("sizing/programs_enqueued", 3)
-            # .tolist()/bool() read the small decision packet — the one
-            # host pull of the round, below the sanitizer's bulk-transfer
-            # accounting (np.asarray / device_get), and the only place
-            # the round waits for the device
-            with span("sizing.sync", cat="sizing"):
-                explored = bool(explored_d)
-                cand_idx = [tuple(int(v) for v in row)
-                            for row in sel.tolist() if row[0] >= 0]
-            if provenance.get() is not None:
-                # armed-only audit pulls (not on the steady-state path)
-                inits = np.asarray(inits_d)
-                table = np.asarray(table_d, np.float64)
-                ys = np.asarray(out["ys"])
-                accepts = np.asarray(out["accepts"])
-                y0 = table[np.ravel_multi_index(tuple(inits.T),
-                                                self._shape)]
-                flat = np.ravel_multi_index(
-                    tuple(np.concatenate(
-                        [inits[:, None, :], np.asarray(out["states"])],
-                        axis=1).reshape(-1, self._enc.ndim).T),
-                    self._shape)
-        else:
-            key_r = jax.random.fold_in(self._key, r)
-            k_init, k_run = jax.random.split(key_r)
+        # prep -> table (on a cache miss) -> anneal -> top-K: three or
+        # four programs enqueued and no eager op; only the (topk, ndim)
+        # decision packet is read back
+        with span("sizing.dispatch", cat="sizing"):
+            key_r, k_init, chain_keys, inits_d, taus_d = \
+                _sizing_prep_jit(self._shape, self.n_chains)(
+                    self._key, _sizing_prep_args(r, self.incumbent, taus),
+                    self._valid_idx)
             with span("sizing.refit", cat="sizing"):
-                table = self._table_for(rates)
-            inits = np.array(
-                random_valid_states(k_init, self._enc, self.n_chains),
-                np.int32)
-            inits[0] = np.asarray(self.incumbent, np.int32)
+                table_d = self._dtable_for(rates, key_r)
             with span("sizing.anneal", cat="sizing"):
                 out = anneal_fleet(
-                    k_run, self._enc,
-                    table.reshape(self._shape).astype(np.float32),
-                    self.steps_per_round,
-                    np.broadcast_to(taus.astype(np.float32),
-                                    (self.n_chains, self.steps_per_round)),
-                    inits=inits, n_chains=self.n_chains)
-
-            with span("sizing.sync", cat="sizing"):
-                states = np.asarray(out["states"])
-            visited = np.concatenate(
-                [inits[:, None, :], states],
-                axis=1).reshape(-1, self._enc.ndim)
-            flat = np.ravel_multi_index(tuple(visited.T), self._shape)
-
-            # exploration: any chain accepted an uphill move this round
-            ys = np.asarray(out["ys"])                    # (n_chains, steps)
+                    k_init, self._enc, table_d, self.steps_per_round,
+                    taus_d, inits=inits_d, n_chains=self.n_chains,
+                    chain_keys=chain_keys)
+            sel, explored_d = _sizing_select_jit(
+                self._shape, self.measure_topk)(
+                inits_d, out["states"], table_d, out["ys"],
+                out["accepts"])
+        if metrics.get() is not None:
+            # prep, anneal and select; _dtable_for counts a table's
+            metrics.inc("sizing/programs_enqueued", 3)
+        # .tolist()/bool() read the small decision packet — the one
+        # host pull of the round, below the sanitizer's bulk-transfer
+        # accounting (np.asarray / device_get), and the only place
+        # the round waits for the device
+        with span("sizing.sync", cat="sizing"):
+            explored = bool(explored_d)
+            cand_idx = [tuple(int(v) for v in row)
+                        for row in sel.tolist() if row[0] >= 0]
+        if provenance.get() is not None:
+            # armed-only audit pulls (not on the steady-state path)
+            inits = np.asarray(inits_d)
+            table = np.asarray(table_d, np.float64)
+            ys = np.asarray(out["ys"])
             accepts = np.asarray(out["accepts"])
-            y0 = table[np.ravel_multi_index(tuple(inits.T), self._shape)]
-            explored = bool(self.explored_flags(ys, accepts, y0).any())
-
-            # speculative ground-truth phase: the compiled fleet's
-            # visited states ARE the engine-enumerated lookahead —
-            # measure the ``measure_topk`` most promising (by table
-            # estimate) on the numpy host model, commit to the *measured*
-            # argmin, and recycle every measurement (mis-speculated
-            # candidates included) into the store.  topk=1 is the
-            # historical inline behavior: re-measure the single best
-            # visited sizing.
-            order = np.argsort(table[flat], kind="stable")
-            cand: list[int] = []
-            seen: set[int] = set()
-            for j in order:
-                f = int(flat[j])
-                if f not in seen:
-                    seen.add(f)
-                    cand.append(f)
-                if len(cand) == self.measure_topk:
-                    break
-            cand_idx = [tuple(int(v)
-                              for v in np.unravel_index(f, self._shape))
-                        for f in cand]
+            y0 = table[np.ravel_multi_index(tuple(inits.T),
+                                            self._shape)]
+            flat = np.ravel_multi_index(
+                tuple(np.concatenate(
+                    [inits[:, None, :], np.asarray(out["states"])],
+                    axis=1).reshape(-1, self._enc.ndim).T),
+                self._shape)
         with span("sizing.measure", cat="sizing"):
             results = self._measure_candidates(cand_idx, rates)
         with span("sizing.commit", cat="sizing"):

@@ -543,12 +543,14 @@ class DeviceMeasurementStore:
 def _select_jit(shape: tuple, acquisition: str, m: int, n_exp: int):
     """Jitted on-device measurement selection: dedup the visited states,
     score them under the acquisition, and pick the ``m`` winners —
-    ``m - n_exp`` by acquisition rank, the rest by uncertainty — exactly
-    the host path's stable-argsort semantics (np.unique's ascending-flat
-    order is reproduced by first-occurrence masking over a stable sort,
-    so ties break identically).  Returns (m, ndim) int32 window-local
-    states with -1 sentinel rows when fewer than ``m`` distinct states
-    were visited."""
+    ``m - n_exp`` by acquisition rank, the rest by uncertainty — in
+    stable-argsort order over the distinct visited states in ascending
+    flat order (first-occurrence masking over a stable sort, so ties
+    break as ``np.unique`` then ``np.argsort(kind="stable")`` break
+    them; the numpy oracle is ``tests/test_surrogate.py::
+    test_window_select_matches_numpy_ranking``).  Returns (m, ndim)
+    int32 window-local states with -1 sentinel rows when fewer than
+    ``m`` distinct states were visited."""
     import jax
     import jax.numpy as jnp
 
@@ -886,7 +888,8 @@ class SurrogateSource(ObjectiveSource):
                              "is valid")
         if self.recycle_store is not None:
             raise ValueError("recycled host measurements warm-start the "
-                             "host table path only (device_loop=False)")
+                             "host table path only: build the table with "
+                             "SurrogateSource.table")
         size = math.prod(space.shape)
         if size > self.max_size:
             raise ValueError(f"space too large to materialize: {size}")
@@ -1095,7 +1098,6 @@ class SurrogateAnnealer:
         seed: int = 0,
         acquisition: str = "lcb",
         eval_workers: int | None = None,
-        device_loop: bool = True,
     ):
         import jax
 
@@ -1138,10 +1140,8 @@ class SurrogateAnnealer:
         self.rounds: list[SurrogateRound] = []
         self._n = 0
         self._enc_cache: dict[tuple[int, ...], Any] = {}
-        # device-resident control loop (tentpole): refit + anneal +
-        # selection stay on device, the numpy store keeps authority over
-        # best()/bootstrap (pure host dict — zero transfers either way)
-        self.device_loop = bool(device_loop)
+        # refit + anneal + selection stay on device; the numpy store keeps
+        # authority over best()/bootstrap (a host dict, no transfers)
         self._dstore: DeviceMeasurementStore | None = None
         self._dstore_version = -1
         self._offs_cache: dict[tuple[int, ...], Any] = {}
@@ -1255,6 +1255,7 @@ class SurrogateAnnealer:
 
     def _round_impl(self) -> SurrogateRound:
         import jax
+        import jax.numpy as jnp
 
         from .annealing import anneal_fleet, random_valid_states
 
@@ -1289,101 +1290,49 @@ class SurrogateAnnealer:
         key_r = jax.random.fold_in(self._key, self._n)
         k_init, k_run = jax.random.split(key_r)
 
-        if self.device_loop:
-            import jax.numpy as jnp
+        # refit -> anneal -> select without a single bulk host
+        # round-trip; only the final (m, ndim) decision packet is read
+        # back
+        self._sync_device_store()
+        mb = min(_bucket(len(self.store)), self._dstore.cap)
+        probes, ys_d, rec_d, live_d = self._dstore.refit_view(t, mb)
+        with span("surrogate.refit", cat="surrogate"):
+            # every window state, enumerated inside the kernel
+            mean_w, dmin_w = _interp_jit(self.model.kind)(
+                probes, ys_d, rec_d, live_d, None,
+                self._window_offsets(offs), qshape=sub.shape,
+                **self.model._static())
+        unc_w = self._dstore.y_scale_device() * dmin_w
+        self.surrogate_queries += W
 
-            # device-resident phase: refit -> anneal -> select without a
-            # single bulk host round-trip; only the final (m, ndim)
-            # decision packet is read back
-            self._sync_device_store()
-            mb = min(_bucket(len(self.store)), self._dstore.cap)
-            probes, ys_d, rec_d, live_d = self._dstore.refit_view(t, mb)
-            with span("surrogate.refit", cat="surrogate"):
-                # every window state, enumerated inside the kernel
-                mean_w, dmin_w = _interp_jit(self.model.kind)(
-                    probes, ys_d, rec_d, live_d, None,
-                    self._window_offsets(offs), qshape=sub.shape,
-                    **self.model._static())
-            unc_w = self._dstore.y_scale_device() * dmin_w
-            self.surrogate_queries += W
-
-            # chain 0 starts at the incumbent (always inside its own
-            # window); the rest uniform over the window's valid region
-            inits_d = random_valid_states(
-                k_init, enc, self.n_chains).astype(jnp.int32)
-            inits_d = inits_d.at[0].set(jnp.asarray(
-                np.asarray(self.incumbent, np.int64) - offs, jnp.int32))
-            bonus = jnp.broadcast_to(
-                (-self.kappa * unc_w).astype(jnp.float32)[None, :],
-                (self.n_chains, W))
-            with span("surrogate.anneal", cat="surrogate"):
-                out = anneal_fleet(
-                    k_run, enc, mean_w.reshape(sub.shape),
-                    self.steps_per_round, self.tau, inits=inits_d,
-                    n_chains=self.n_chains, extra_costs=bonus)
-            sel = _select_jit(sub.shape, self.acquisition,
-                              self.measures_per_round, n_exp)(
-                inits_d, out["states"], mean_w, unc_w,
-                jnp.float32(self.kappa), jnp.float32(self._best(t)[1]))
-            # .tolist() reads the m*ndim-int decision packet — the one
-            # host pull of the round, below the sanitizer's bulk-transfer
-            # accounting (np.asarray / device_get), and its one wait for
-            # the device
-            with span("surrogate.sync", cat="surrogate"):
-                rows = sel.tolist()
-            with span("surrogate.measure", cat="surrogate"):
-                measured.extend(self._measure_states(
-                    [tuple(int(v) + int(o) for v, o in zip(r, offs))
-                     for r in rows if r[0] >= 0], t))
-        else:
-            grid = np.indices(sub.shape).reshape(len(sub.shape), -1).T
-            with span("surrogate.refit", cat="surrogate",
-                      metric="surrogate/refit_s"):
-                mean, unc = self.model.predict(grid + offs, self.store,
-                                               now=t)
-            self.surrogate_queries += W
-
-            # chain 0 starts at the incumbent (always inside its own
-            # window); the rest start uniform over the window's valid
-            # region
-            inits = np.array(
-                random_valid_states(k_init, enc, self.n_chains), np.int32)
-            inits[0] = np.asarray(self.incumbent, np.int64) - offs
-            bonus = np.broadcast_to((-self.kappa * unc).astype(np.float32),
-                                    (self.n_chains, W))
-            with span("surrogate.anneal", cat="surrogate"):
-                out = anneal_fleet(
-                    k_run, enc, mean.reshape(sub.shape).astype(np.float32),
-                    self.steps_per_round, self.tau, inits=inits,
-                    n_chains=self.n_chains, extra_costs=bonus)
-
-            # candidate pool: every state any chain visited (step-0
-            # included)
-            visited = np.concatenate(
-                [inits[:, None, :], np.asarray(out["states"])],
-                axis=1).reshape(-1, enc.ndim)
-            visited = np.unique(visited, axis=0)
-            vflat = np.ravel_multi_index(tuple(visited.T), sub.shape)
-            if self.acquisition == "ei":
-                # lower score = measured earlier, so negate the
-                # improvement
-                acq = -expected_improvement(
-                    mean[vflat], unc[vflat], self._best(t)[1])
-            else:
-                acq = mean[vflat] - self.kappa * unc[vflat]
-
-            by_acq = np.argsort(acq, kind="stable")
-            by_unc = np.argsort(-unc[vflat], kind="stable")
-            chosen: list[int] = []
-            for pos in (list(by_acq[:self.measures_per_round - n_exp])
-                        + list(by_unc)):
-                if pos not in chosen:
-                    chosen.append(int(pos))
-                if len(chosen) == self.measures_per_round:
-                    break
-            with span("surrogate.measure", cat="surrogate"):
-                measured.extend(self._measure_states(
-                    [visited[pos] + offs for pos in chosen], t))
+        # chain 0 starts at the incumbent (always inside its own
+        # window); the rest uniform over the window's valid region
+        inits_d = random_valid_states(
+            k_init, enc, self.n_chains).astype(jnp.int32)
+        inits_d = inits_d.at[0].set(jnp.asarray(
+            np.asarray(self.incumbent, np.int64) - offs, jnp.int32))
+        bonus = jnp.broadcast_to(
+            (-self.kappa * unc_w).astype(jnp.float32)[None, :],
+            (self.n_chains, W))
+        with span("surrogate.anneal", cat="surrogate"):
+            out = anneal_fleet(
+                k_run, enc, mean_w.reshape(sub.shape),
+                self.steps_per_round, self.tau, inits=inits_d,
+                n_chains=self.n_chains, extra_costs=bonus)
+        sel = _select_jit(sub.shape, self.acquisition,
+                          self.measures_per_round, n_exp)(
+            inits_d, out["states"], mean_w, unc_w,
+            jnp.float32(self.kappa), jnp.float32(self._best(t)[1]))
+        # .tolist() reads the m*ndim-int decision packet — the one
+        # host pull of the round, below the sanitizer's bulk-transfer
+        # accounting (np.asarray / device_get), and its one wait for
+        # the device
+        with span("surrogate.sync", cat="surrogate"):
+            rows = sel.tolist()
+        with span("surrogate.measure", cat="surrogate"):
+            measured.extend(self._measure_states(
+                [tuple(int(v) + int(o) for v, o in zip(r, offs))
+                 for r in rows if r[0] >= 0], t))
 
         self.incumbent, best_y = self._best(t)
         rec = SurrogateRound(
@@ -1393,15 +1342,10 @@ class SurrogateAnnealer:
             measured=tuple(measured))
         self.rounds.append(rec)
         if provenance.get() is not None:
-            if self.device_loop:
-                self._record_round_provenance(
-                    rec, prev_inc, measured, out, np.asarray(inits_d),
-                    np.asarray(mean_w, np.float64),
-                    np.asarray(unc_w, np.float64), sub, offs)
-            else:
-                self._record_round_provenance(
-                    rec, prev_inc, measured, out, inits, mean, unc, sub,
-                    offs)
+            self._record_round_provenance(
+                rec, prev_inc, measured, out, np.asarray(inits_d),
+                np.asarray(mean_w, np.float64),
+                np.asarray(unc_w, np.float64), sub, offs)
         self._n += 1
         note_round("SurrogateAnnealer", self)
         return rec

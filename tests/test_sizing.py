@@ -10,6 +10,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import (
@@ -29,7 +30,8 @@ from repro.core import (
     full_grid,
     microservice_config_fn,
 )
-from repro.core.sizing import sizing_table_device
+from repro.core.procurement import ControllerMixin
+from repro.core.sizing import _sizing_select_jit, sizing_table_device
 from repro.kernels.ref import sizing_entry_latency_ref, sizing_latency_ref
 from repro.kernels.sizing_latency import sizing_latency
 from repro.workloads.microservice import (
@@ -385,7 +387,7 @@ def test_sizing_controller_is_deterministic_under_seed():
 
 @pytest.mark.parametrize("masked", [False, True])
 def test_sizing_prep_program_matches_the_eager_sequence(masked):
-    """The device loop's prep program draws the round key, the chains'
+    """The round's prep program draws the round key, the chains'
     keys, the inits and the temperatures bit for bit as the eager
     sequence it replaced: ``fold_in``, ``split``, the inits (uniform, or
     over the valid region), row 0 set to the incumbent, then
@@ -437,6 +439,68 @@ def test_sizing_prep_program_matches_the_eager_sequence(masked):
             assert mask[tuple(np.asarray(got[3])[1:].T)].all()
 
 
+def _host_topk(inits, states, table, topk, shape):
+    """numpy top-K over the visited states: stable argsort of their table
+    estimates (ties by visit position, chain-major), first-distinct
+    dedup, -1 rows past the distinct states."""
+    nd = inits.shape[1]
+    visited = np.concatenate([inits[:, None, :], states],
+                             axis=1).reshape(-1, nd)
+    flat = np.ravel_multi_index(tuple(visited.T), shape)
+    cand: list[int] = []
+    for j in np.argsort(table[flat], kind="stable"):
+        if int(flat[j]) not in cand:
+            cand.append(int(flat[j]))
+        if len(cand) == topk:
+            break
+    out = np.full((topk, nd), -1, np.int64)
+    for i, f in enumerate(cand):
+        out[i] = np.unravel_index(f, shape)
+    return out
+
+
+@pytest.mark.parametrize("topk", [1, 4])
+def test_sizing_select_matches_host_topk(topk):
+    """The device top-K select against its numpy oracle, on a float32
+    table whose minimum is tied between states visited by different
+    chains, so the chain-major tie order decides the pick."""
+    shape, C, steps = (4, 3, 5), 4, 6
+    rng = np.random.default_rng(11)
+    inits = np.stack([rng.integers(0, n, C) for n in shape],
+                     axis=1).astype(np.int32)
+    states = np.stack([rng.integers(0, n, (C, steps)) for n in shape],
+                      axis=2).astype(np.int32)
+    table = rng.integers(2, 6, int(np.prod(shape))).astype(np.float32)
+    visited = np.concatenate([inits[:, None, :], states], axis=1)
+    tied = [np.ravel_multi_index(tuple(visited[c, 2 + c]), shape)
+            for c in (3, 1, 2)]
+    assert len(set(tied)) == 3
+    table[tied] = 1.0                                # three-way tie
+    # a sound tie order matters: reversed visit order picks otherwise
+    want = _host_topk(inits, states, table, topk, shape)
+    rev = _host_topk(inits[::-1], states[::-1], table, topk, shape)
+    assert not np.array_equal(want, rev)
+
+    select = _sizing_select_jit(shape, topk)
+    iflat = np.ravel_multi_index(tuple(inits.T), shape)
+    ys = table[np.ravel_multi_index(tuple(states.reshape(-1, 3).T),
+                                    shape)].reshape(C, steps)
+    flags = []
+    for accepts in (rng.random((C, steps)) < 0.5,
+                    np.zeros((C, steps), bool)):
+        sel, explored = select(inits, states, table, ys, accepts)
+        np.testing.assert_array_equal(np.asarray(sel), want)
+        flags.append(bool(explored))
+        assert flags[-1] == bool(ControllerMixin.explored_flags(
+            ys, accepts, table[iflat]).any())
+    assert flags == [True, False]
+    # fewer distinct visited states than topk: -1 sentinel rows
+    same = np.broadcast_to(inits[:1, None, :], states.shape)
+    sel, _ = select(inits[:1], same[:1], table, ys[:1], accepts[:1])
+    np.testing.assert_array_equal(
+        np.asarray(sel), _host_topk(inits[:1], same[:1], table, topk, shape))
+
+
 def test_sizing_controller_refuses_large_space_without_source():
     spec = _spec(sizes=(ContainerSize("s", 1, 2.0),
                         ContainerSize("m", 2, 4.0),
@@ -448,15 +512,61 @@ def test_sizing_controller_refuses_large_space_without_source():
 
 def test_sizing_controller_exhaustive_source_matches_batched_table():
     """The scalar one-state-at-a-time seam and the batched whole-grid
-    tabulation must produce the same table (they share the math)."""
+    tabulation must produce the same device table (they share the
+    math)."""
     spec = _spec(replica_counts=(1, 2))             # 4^6 = 4096 states
     a = SizingController(spec, MIX_BROWSE, seed=0)
     b = SizingController(spec, MIX_BROWSE,
                          objective_source=ExhaustiveSource(), seed=0)
-    ta = a._table_for(MIX_BROWSE)
-    tb = b._table_for(MIX_BROWSE)
+    key = jax.random.key(0)
+    ta = np.asarray(a._dtable_for(MIX_BROWSE, key))
+    tb = np.asarray(b._dtable_for(MIX_BROWSE, key))
+    assert ta.shape == tb.shape == (spec.space.size(),)
+    assert tb.dtype == np.float32
     np.testing.assert_allclose(ta, tb, rtol=2e-4)
     assert b.objective_source.true_measures == spec.space.size()
+
+
+def test_sizing_table_cache_keeps_the_last_mixes():
+    """Under a drifting mix the table cache holds at most TABLE_CACHE
+    tables: a recurring mix is a hit (no rebuild, moved to the fresh
+    end) and a new mix evicts the stalest."""
+    spec = _spec(replica_counts=(1, 2))             # 4096 states
+    cap = SizingController.TABLE_CACHE
+    mixes = [{"browse": 40.0 + i, "checkout": 8.0} for i in range(cap + 4)]
+    # rounds 0..cap-1 fill the cache, round cap revisits mix 0, then
+    # four new mixes
+    order = list(range(cap)) + [0] + list(range(cap, cap + 4))
+    ctrl = SizingController(spec, lambda r: mixes[order[r]],
+                            steps_per_round=8, n_chains=4, seed=0)
+    ctrl.run(cap)
+    key0 = ctrl._mix_key(mixes[0])
+    t0 = ctrl._dtables[key0]
+    assert len(ctrl._dtables) == cap
+    builds = ctrl.evaluation_counts()["true_measures"]
+    ctrl.round()                                    # mix 0 again: a hit
+    assert ctrl._dtables[key0] is t0
+    assert list(ctrl._dtables)[-1] == key0
+    # a hit tabulates nothing; only the round's one re-measure counts
+    assert ctrl.evaluation_counts()["true_measures"] == builds + 1
+    ctrl.run(4)
+    want = [ctrl._mix_key(mixes[i])
+            for i in list(range(5, cap)) + [0] + list(range(cap, cap + 4))]
+    assert list(ctrl._dtables) == want
+    assert ctrl._dtables[key0] is t0
+
+
+def test_sizing_controller_refuses_a_recycling_surrogate_source():
+    """A recycle store's measurements live on the host and warm-start
+    only SurrogateSource.table; the sizing round builds its tables on
+    the device, so the controller refuses such a source up front."""
+    from repro.core import MeasurementStore
+
+    spec = _spec(replica_counts=(1, 2))
+    store = MeasurementStore(len(spec.space.dimensions))
+    src = SurrogateSource(n_probe=16, recycle_store=store)
+    with pytest.raises(ValueError, match="recycle_store"):
+        SizingController(spec, MIX_BROWSE, objective_source=src)
 
 
 def test_sizing_controller_surrogate_source_runs_with_sparse_probes():
@@ -597,7 +707,8 @@ def test_surrogate_round_uploads_no_table_and_reads_back_only_the_packet(
         assert rounds[0]["entries"]["surrogate_refit"]["calls"] == 1
         assert all(d["compiles"] == 0
                    for r in rounds[1:] for d in r["entries"].values())
-        assert ctrl._tables == {}
+        assert all(isinstance(t, jax.Array)
+                   for t in ctrl._dtables.values())
     finally:
         if not pre_armed:
             sanitize.uninstall()

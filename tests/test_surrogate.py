@@ -243,6 +243,49 @@ def test_surrogate_annealer_ei_converges_on_960_state_validation_space():
     assert (y_best - y_star) / abs(y_star) <= 0.05
 
 
+@pytest.mark.parametrize("acquisition", ["lcb", "ei"])
+def test_window_select_matches_numpy_ranking(acquisition):
+    """The device measurement select against its numpy oracle on the
+    same float32 mean and uncertainty: the distinct visited states in
+    ascending flat order (np.unique), the first ``m - n_exp`` by
+    acquisition rank, then the rest by uncertainty, both stable."""
+    from repro.core import expected_improvement
+    from repro.core.surrogate import _select_jit
+
+    shape, C, steps, m, n_exp = (5, 4, 3), 4, 12, 6, 2
+    kappa, y_best = 1.5, 2.0
+    rng = np.random.default_rng(5)
+    size = int(np.prod(shape))
+    # quarter steps: exact in float32, so equal scores tie exactly
+    mean = (0.5 * rng.integers(0, 10, size)).astype(np.float32)
+    unc = (0.25 * rng.integers(0, 5, size)).astype(np.float32)
+    inits = np.stack([rng.integers(0, n, C) for n in shape],
+                     axis=1).astype(np.int32)
+    states = np.stack([rng.integers(0, n, (C, steps)) for n in shape],
+                      axis=2).astype(np.int32)
+
+    visited = np.unique(np.concatenate(
+        [inits[:, None, :], states], axis=1).reshape(-1, 3), axis=0)
+    vflat = np.ravel_multi_index(tuple(visited.T), shape)
+    mv, uv = mean[vflat].astype(np.float64), unc[vflat].astype(np.float64)
+    acq = (-expected_improvement(mv, uv, y_best) if acquisition == "ei"
+           else mv - kappa * uv)
+    chosen: list[int] = []
+    for pos in (list(np.argsort(acq, kind="stable")[:m - n_exp])
+                + list(np.argsort(-uv, kind="stable"))):
+        if pos not in chosen:
+            chosen.append(int(pos))
+        if len(chosen) == m:
+            break
+    want = visited[chosen]
+    assert len(vflat) > m and len(set(acq.tolist())) < len(acq)
+
+    sel = _select_jit(shape, acquisition, m, n_exp)(
+        inits, states, jnp.asarray(mean), jnp.asarray(unc),
+        jnp.float32(kappa), jnp.float32(y_best))
+    np.testing.assert_array_equal(np.asarray(sel), want)
+
+
 def test_surrogate_annealer_rejects_unknown_acquisition():
     with pytest.raises(ValueError, match="acquisition"):
         SurrogateAnnealer(_smooth_space(20), _smooth_fn,

@@ -665,24 +665,12 @@ def test_warm_sizing_round_binds_no_eager_primitive(monkeypatch, table,
     assert counters["sizing/programs_enqueued"] == programs
 
 
-def test_sizing_host_path_waits_in_its_sync_span():
-    ctl = _sizing()
-    ctl.device_loop = False
-    with telemetry.session() as tel:
-        ctl.run(2)
-    assert _children(tel.spans.spans(), "sizing.round", 1) == [
-        ["sizing.refit", "sizing.anneal", "sizing.sync", "sizing.measure",
-         "sizing.commit"]] * 2
-
-
-@pytest.mark.parametrize("device_loop", [True, False])
-def test_sizing_decisions_identical_armed_and_dark(device_loop):
+def test_sizing_decisions_identical_armed_and_dark():
     """The spans reorder no device work and draw no key: the same seeded
     controller commits bit-identical decisions armed and dark."""
 
     def run(armed: bool):
         ctl = _sizing()
-        ctl.device_loop = device_loop
         if armed:
             with telemetry.session():
                 ds = ctl.run(6)
