@@ -2,7 +2,8 @@
 """Run the online controllers once on a TPU, at the repo's largest sizes.
 
     python chip_smoke.py               # one chip: fleet, sizing, rich sizing,
-                                       # surrogate, kernel-vs-reference parity
+                                       # surrogate, kernel-vs-reference parity,
+                                       # the IDW weight against division
     python chip_smoke.py --four-chips  # four chips: the 1,024-tenant replay
                                        # sharded over a "tenants" mesh against
                                        # direct dispatch, and nothing else
@@ -39,6 +40,9 @@ STORE_CAP = 8192
 PARITY_Q = 1024
 #: tests/test_kernels.py's float32 kernel-vs-reference tolerance.
 KERNEL_TOL = dict(atol=2e-5, rtol=1e-4)
+#: The IDW weight's check: every float32 bit pattern from the smallest
+#: normal up to 2^64, in chunks of 2^24 (95 chunks).
+WEIGHT_LO, WEIGHT_HI, WEIGHT_CHUNK = 0x00800000, 0x5F800000, 1 << 24
 
 
 class Checks:
@@ -296,6 +300,51 @@ def phase_surrogate(check: Checks, rounds: int = 6,
           np.allclose(d2, d2_ref, **KERNEL_TOL))
 
 
+def phase_idw_weight(check: Checks, lo: int = WEIGHT_LO,
+                     hi: int = WEIGHT_HI, chunk: int = WEIGHT_CHUNK) -> None:
+    """``fused_interp``'s IDW weight (the approximate reciprocal plus one
+    Newton step) against IEEE ``1.0 / x`` in a Pallas kernel, bit for bit,
+    on every normal positive float32 ``x`` in ``[lo, hi)`` as bit
+    patterns: the kernel's denominators lie in ``[eps, ndim + eps]``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from repro.kernels.surrogate_distance import _idw_weight
+
+    def kern(x_ref, got_ref, want_ref):
+        x = x_ref[...]
+        got_ref[...] = _idw_weight(x)
+        want_ref[...] = 1.0 / x
+
+    rows = chunk // 128
+    f32 = jax.ShapeDtypeStruct((rows, 128), jnp.float32)
+
+    @jax.jit
+    def gap(base):
+        bits = base + jax.lax.iota(jnp.uint32, chunk)
+        x = jax.lax.bitcast_convert_type(bits, jnp.float32)
+        got, want = pl.pallas_call(
+            kern, grid=(rows // 1024,),
+            in_specs=[pl.BlockSpec((1024, 128), lambda i: (i, 0))],
+            out_specs=[pl.BlockSpec((1024, 128), lambda i: (i, 0))] * 2,
+            out_shape=[f32, f32])(x.reshape(rows, 128))
+        ulps = jnp.abs(jax.lax.bitcast_convert_type(got, jnp.int32)
+                       - jax.lax.bitcast_convert_type(want, jnp.int32))
+        return jnp.sum(ulps != 0), jnp.max(ulps)
+
+    t0 = time.perf_counter()
+    differ, worst = 0, 0
+    for base in range(lo, hi, chunk):
+        n, m = gap(jnp.uint32(base))
+        differ, worst = differ + int(n), max(worst, int(m))
+    print(f"[idw_weight] {hi - lo:,} float32 values in "
+          f"{time.perf_counter() - t0:.6f} s", flush=True)
+    check("idw_weight", f"reciprocal plus Newton step equals 1.0 / x bit "
+                        f"for bit on {hi - lo:,} values: {differ} differ, "
+                        f"largest gap {worst} ulp", differ == 0)
+
+
 def phase_four_chips(check: Checks, T: int = FLEET_T,
                      rounds: int = FLEET_ROUNDS) -> None:
     """The fleet's chain dispatch sharded over a 4-device "tenants" mesh
@@ -399,6 +448,7 @@ def main(argv=None) -> int:
         phase_sizing(check)
         phase_rich_sizing(check)
         phase_surrogate(check)
+        phase_idw_weight(check)
     check("process", "repro.launch.dryrun (512 host devices) never "
                      "imported", "repro.launch.dryrun" not in sys.modules)
     print(f"total {time.perf_counter() - t0:.6f} s; compile cache: "

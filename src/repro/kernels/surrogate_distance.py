@@ -30,7 +30,17 @@ Its layout puts the query states on lanes:
   IDW must reproduce the measurement;
 * measurements sit on sublanes in 128-row chunks; the weighted sums
   reduce over sublanes into (1, block_q) accumulators, and the outputs
-  are lane-dense (Q,) rows.
+  are lane-dense (Q,) rows;
+* the IDW weight ``1 / (d^p + eps)`` is the hardware's approximate
+  reciprocal plus one Newton step, ``r + r * (1 - x * r)``: the sequence
+  the TPU compiler emits for ``1.0 / x``, without the fix-ups it appends
+  for 0, infinity, NaN and the 2^126 overflow edge.  With ``eps`` a
+  normal positive float32, ``x = d^p + eps`` is a finite normal positive
+  number (``d2`` sums non-negative costs, at most ``ndim``), no fix-up
+  can fire, and on the chip the weight is bit-identical to the
+  division's (interpret mode's approximate reciprocal is a bfloat16 one,
+  so there the step leaves about 2^-16 of relative error).  IDW refuses
+  a zero, denormal or NaN ``eps``.
 """
 
 from __future__ import annotations
@@ -113,6 +123,14 @@ _CHUNK_M = 128
 _BLOCK_M = 1024
 
 
+def _idw_weight(x):
+    """``1 / x`` for a finite, normal, positive float32 ``x``, inside a
+    kernel: the approximate reciprocal and one Newton step, in the order
+    the TPU compiler emits them for ``1.0 / x``."""
+    r = pl.reciprocal(x, approx=True)
+    return r + r * (1.0 - x * r)
+
+
 def _digit(flat, stride: int, n: int):
     """Row-major axis value of flat indices (static stride and length):
     shifts and masks where both are powers of two, else division."""
@@ -178,7 +196,7 @@ def _fused_interp_kernel(*refs, qshape, segs, n_split, explicit, kind,
             k = jnp.exp(d2 * (-1.0 / (2.0 * length_scale * length_scale)))
         else:                                         # "idw" (Shepard)
             dp = d2 if idw_power == 2.0 else d2 ** (idw_power / 2.0)
-            k = 1.0 / (dp + eps)
+            k = _idw_weight(dp + eps)
         k = k * w_ref[pl.ds(r0, _CHUNK_M), :]
         ky = ky + jnp.sum(k * y_ref[pl.ds(r0, _CHUNK_M), :], axis=0,
                           keepdims=True)
@@ -247,7 +265,8 @@ def fused_interp(probes, y, w_rec, *, shape, categorical=None,
     the nearest live measurement, or ``mean`` alone with ``with_dmin``
     False.  The estimate is kernel-weighted with the recency-weighted
     global mean as the far-field fallback.  ``kind`` / ``length_scale`` /
-    ``idw_power`` / ``eps`` are Python-static (baked into the trace).
+    ``idw_power`` / ``eps`` are Python-static (baked into the trace);
+    IDW takes ``eps`` at least the smallest normal float32.
     """
     shape = tuple(int(n) for n in shape)
     ndim = len(shape)
@@ -257,6 +276,9 @@ def fused_interp(probes, y, w_rec, *, shape, categorical=None,
         raise ValueError(f"probes shape {probes.shape} != (M, {ndim})")
     if kind not in ("idw", "rbf"):
         raise ValueError(f"unknown interp kind {kind!r}")
+    if kind == "idw" and not eps >= 2.0 ** -126:     # least normal f32
+        raise ValueError(f"IDW eps {eps!r} is not a normal positive "
+                         "float32: the weight's reciprocal needs one")
     if block_q < 128 or block_q % 128:
         raise ValueError("block_q must be a positive multiple of 128")
     explicit = queries is not None

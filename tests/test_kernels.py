@@ -383,6 +383,63 @@ def test_fused_interp_enumerated_queries_vs_ref(kind, shape, qshape,
                                atol=2e-5, rtol=1e-4)
 
 
+@pytest.mark.parametrize("idw_power,eps", [(2.0, 1e-9), (3.0, 1e-9),
+                                           (2.0, 1e-3)])
+def test_fused_interp_idw_reciprocal_vs_ref(idw_power, eps):
+    """The IDW weight is the approximate reciprocal plus one Newton
+    step, at a tiny and a large ``eps``.  Every state of (2,)*12 against
+    1,500 distinct probes, two grid columns of measurements, so the
+    accumulators carry from one column to the next.  Every probe is also
+    a query state, where a tiny ``eps`` must give back the probe's own
+    measurement.  In interpret mode the approximate reciprocal is a
+    bfloat16 one, so the Newton step starts farther off than on the
+    chip."""
+    from repro.kernels.surrogate_distance import fused_interp
+    rng = np.random.default_rng(12)
+    shape = (2,) * 12
+    cat = (False,) * len(shape)
+    flat = rng.choice(2 ** len(shape), size=1500, replace=False)
+    probes = np.stack(np.unravel_index(flat, shape), axis=1)
+    y = jnp.asarray(rng.normal(size=(1500,)), jnp.float32)
+    w = jnp.asarray(rng.uniform(0.1, 1.0, size=(1500,)), jnp.float32)
+
+    def interp(p, y, w):
+        return fused_interp(p, y, w, shape=shape, categorical=cat,
+                            kind="idw", idw_power=idw_power, eps=eps)
+
+    # the traced program holds the kernel's body
+    assert "reciprocal[approx=True]" in str(
+        jax.make_jaxpr(interp)(jnp.asarray(probes), y, w))
+    mean, dmin = interp(jnp.asarray(probes), y, w)
+    grid = np.indices(shape).reshape(len(shape), -1).T
+    want_mean, want_dmin = ref.fused_interp_ref(
+        _features(grid, shape, cat), _features(probes, shape, cat), y, w,
+        kind="idw", idw_power=idw_power, eps=eps)
+    np.testing.assert_allclose(np.asarray(mean), np.asarray(want_mean),
+                               atol=2e-5, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(dmin), np.asarray(want_dmin),
+                               atol=2e-5, rtol=1e-4)
+    if eps < 1e-6:      # the probe's weight outweighs all others by ~1e6
+        np.testing.assert_allclose(np.asarray(mean)[flat], np.asarray(y),
+                                   atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("eps", [0.0, 1e-39, float("nan")])
+def test_fused_interp_idw_refuses_an_eps_below_normal(eps):
+    """The IDW weight's reciprocal holds for a normal positive
+    denominator only: a zero, denormal or NaN ``eps`` is refused before
+    anything is traced, and RBF, which takes no ``eps``, still runs."""
+    from repro.kernels.surrogate_distance import fused_interp
+    shape = (2,) * 4
+    probes = jnp.asarray(np.indices(shape).reshape(4, -1).T)
+    y = jnp.arange(16, dtype=jnp.float32)
+    w = jnp.ones((16,), jnp.float32)
+    with pytest.raises(ValueError, match="not a normal positive float32"):
+        fused_interp(probes, y, w, shape=shape, kind="idw", eps=eps)
+    mean, _ = fused_interp(probes, y, w, shape=shape, kind="rbf", eps=eps)
+    assert np.isfinite(np.asarray(mean)).all()
+
+
 def test_fused_interp_zero_weight_rows_contribute_nothing():
     """The pow-2-bucket padding contract: rows with zero recency weight
     and marked dead (the device store's empty slots) must not shift the
